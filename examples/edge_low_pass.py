@@ -12,7 +12,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import _env  # noqa: F401  (JAX_PLATFORMS=cpu honor shim)
 import tempfile
 import threading
 import time

@@ -3,8 +3,9 @@
 The same LFProc workflow as examples/batch_low_pass.py, but every
 per-window kernel runs over a (time, ch) device mesh: channels split
 with zero communication; cascade-aligned windows also shard the time
-axis with a one-sided ICI halo exchange. Output is bit-identical to the
-single-device run (asserted below).
+axis with a one-sided ICI halo exchange. Output matches the
+single-device run to a few f32 ulps (asserted below; channel-only
+sharding is bit-identical).
 
 On a v5e-8 use the real chips; anywhere else this demonstrates on
 virtual CPU devices:
@@ -18,7 +19,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import _env  # noqa: F401  (JAX_PLATFORMS=cpu honor shim)
 import tempfile
 import time
 
@@ -91,10 +91,11 @@ def main():
             f"timings={ {k: round(v, 3) for k, v in lfp.timings.items()} }"
         )
 
-    assert np.array_equal(results["single-device"], results["mesh"]), (
-        "sharded output diverged!"
-    )
-    print("sharded output is bit-identical to single-device ✓")
+    from tpudas.testing import max_ulps
+
+    ulps = max_ulps(results["mesh"], results["single-device"])
+    assert ulps <= 4, f"sharded output diverged! ({ulps} ulps)"
+    print(f"sharded output matches single-device ({ulps:.2f} ulps) ✓")
     print(f"outputs in {workdir}")
 
 

@@ -29,8 +29,6 @@ def main():
     from jax.experimental import multihost_utils
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from tpudas.parallel.compat import shard_map
-
     from tpudas.parallel.halo import exchange_halo_time
 
     assert jax.process_count() == 2, jax.process_count()
@@ -50,7 +48,7 @@ def main():
 
     @jax.jit
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("time", "ch"),),
         out_specs=P(),
@@ -67,7 +65,7 @@ def main():
 
     @jax.jit
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("time", "ch"),),
         out_specs=P("time", "ch"),

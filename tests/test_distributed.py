@@ -95,7 +95,7 @@ class TestRealTwoProcessDCN:
         worker = os.path.join(os.path.dirname(__file__), "dcn_worker.py")
         procs = []
         for pid in range(2):
-            env = g._clean_cpu_env(4)  # 4 virtual devices per process
+            env = g._cpu_env(4)  # 4 virtual devices per process
             env.update(
                 COORDINATOR_ADDRESS=f"127.0.0.1:{port}",
                 NUM_PROCESSES="2",

@@ -181,7 +181,7 @@ class TestPallasKernel:
     @pytest.mark.slow
     def test_mosaic_knob_variants_bit_equal(self, monkeypatch, env):
         """The Mosaic experiment knobs (grid order, dimension
-        semantics, VMEM cap — swept on chip by chip_campaign2 step 5)
+        semantics, VMEM cap — chip sweep knobs)
         must not change kernel OUTPUT, only its schedule: every
         variant is bit-equal to the default lowering."""
         from tpudas.ops.pallas_fir import (
@@ -446,208 +446,127 @@ class TestStageEngines:
             assert sum(lfp.engine_counts.values()) == 4
 
 
-class TestPallasFallback:
-    @pytest.mark.slow
-    def test_lfproc_catches_silently_wrong_pallas_numbers(
-        self, tmp_path, monkeypatch, capsys
-    ):
-        """A Mosaic miscompile that RETURNS (no exception) wrong
-        numbers is caught by the first-window cross-check against the
-        XLA formulation and handled exactly like a compile failure:
-        the run completes on the XLA cascade with correct output."""
+class TestPallasFailureRaises:
+    """A Pallas failure on the batch or stream path raises: no code
+    path catches it and carries on under another engine."""
+
+    @pytest.fixture()
+    def forced_pallas(self, monkeypatch):
+        """Route every cascade stage to the Pallas kernel on the CPU
+        backend (interpret mode) and clear the compiled caches around
+        the test."""
         import tpudas.ops.fir as fir_mod
-        import tpudas.ops.pallas_fir as pf_mod
-        from tpudas import spool
-        from tpudas.proc.lfproc import LFProc
-        from tpudas.testing import make_synthetic_spool
-        from tpudas.utils.logging import set_log_handler
 
-        d = tmp_path / "raw"
-        make_synthetic_spool(
-            d, n_files=4, file_duration=30.0, fs=100.0, n_ch=6, noise=0.01
-        )
-
-        real = pf_mod.fir_decimate_pallas
-
-        def corrupt(x, hb, R, n_out, **kw):
-            # silently wrong: scaled output, nothing raised (covers
-            # both impls, so the v1 retry is caught by the same check)
-            return real(x, hb, R, n_out=n_out, **kw) * 1.7
-
-        monkeypatch.delenv("TPUDAS_PALLAS_IMPL", raising=False)
-        fir_mod._layout_for.cache_clear()
         fir_mod._clear_cascade_caches()
         monkeypatch.setattr(
             fir_mod, "resolve_cascade_engine",
             lambda e="auto": "pallas" if e == "auto" else e,
         )
         monkeypatch.setattr(fir_mod, "_pallas_stage_ok", lambda *a: True)
-        monkeypatch.setattr(pf_mod, "fir_decimate_pallas", corrupt)
-        events = []
-        set_log_handler(events.append)
-        try:
-            lfp = LFProc(spool(str(d)).sort("time").update())
-            lfp.update_processing_parameter(
-                output_sample_interval=1.0,
-                process_patch_size=60,
-                edge_buff_size=10,
-            )
-            out = tmp_path / "out"
-            lfp.set_output_folder(str(out), delete_existing=True)
-            lfp.process_time_range(
-                np.datetime64("2023-03-22T00:00:00"),
-                np.datetime64("2023-03-22T00:02:00"),
-            )
-        finally:
-            os.environ.pop("TPUDAS_PALLAS_IMPL", None)
-            set_log_handler(None)
-            fir_mod._layout_for.cache_clear()
-            fir_mod._clear_cascade_caches()
-        assert not lfp._pallas_ok
-        assert lfp.engine_counts["cascade-pallas"] == 0
-        assert lfp.engine_counts["cascade-xla"] == sum(
-            lfp.engine_counts.values()
+        yield
+        fir_mod._clear_cascade_caches()
+
+    @staticmethod
+    def _run_batch(tmp_path):
+        from tpudas import spool
+        from tpudas.proc.lfproc import LFProc
+        from tpudas.testing import make_synthetic_spool
+
+        d = tmp_path / "raw"
+        make_synthetic_spool(
+            d, n_files=4, file_duration=30.0, fs=100.0, n_ch=6, noise=0.01
         )
-        falls = [e for e in events if e["event"] == "pallas_fallback"]
-        assert len(falls) == 1
-        assert "pallas-vs-xla rel err" in falls[0]["error"]
-        # and the emitted output is the CORRECT numbers: re-run on a
-        # clean processor (no corruption monkeypatch active on its
-        # windows' engine choice would matter — it lands on XLA the
-        # same way) and require byte-identical files
-        lfp2 = LFProc(spool(str(d)).sort("time").update())
-        lfp2.update_processing_parameter(
+        lfp = LFProc(spool(str(d)).sort("time").update())
+        lfp.update_processing_parameter(
             output_sample_interval=1.0,
             process_patch_size=60,
             edge_buff_size=10,
         )
-        out2 = tmp_path / "out2"
-        lfp2.set_output_folder(str(out2), delete_existing=True)
-        lfp2.process_time_range(
+        lfp.set_output_folder(str(tmp_path / "out"), delete_existing=True)
+        lfp.process_time_range(
             np.datetime64("2023-03-22T00:00:00"),
             np.datetime64("2023-03-22T00:02:00"),
         )
-        import filecmp
+        return lfp
 
-        files = sorted(p.name for p in out.iterdir())
-        assert files == sorted(p.name for p in out2.iterdir())
-        for name in files:
-            assert filecmp.cmp(out / name, out2 / name, shallow=False)
-
-    def test_lfproc_survives_pallas_compile_failure(
-        self, tmp_path, monkeypatch, capsys
+    def test_lfproc_pallas_compile_failure_raises(
+        self, tmp_path, monkeypatch, forced_pallas
     ):
-        """A Mosaic/compile failure of the Pallas fast path must not
-        kill the run: LFProc permanently falls back to the XLA cascade
-        (same numerics) and records the ground truth."""
-        import tpudas.ops.fir as fir_mod
         import tpudas.ops.pallas_fir as pf_mod
-        from tpudas import spool
-        from tpudas.proc.lfproc import LFProc
-        from tpudas.testing import make_synthetic_spool
-
-        d = tmp_path / "raw"
-        make_synthetic_spool(
-            d, n_files=4, file_duration=30.0, fs=100.0, n_ch=6, noise=0.01
-        )
 
         def boom(*a, **k):
             raise RuntimeError("mosaic compile failure (synthetic)")
 
-        monkeypatch.delenv("TPUDAS_PALLAS_IMPL", raising=False)
-        fir_mod._layout_for.cache_clear()
-        fir_mod._clear_cascade_caches()
-        monkeypatch.setattr(
-            fir_mod, "resolve_cascade_engine",
-            lambda e="auto": "pallas" if e == "auto" else e,
-        )
-        monkeypatch.setattr(
-            fir_mod, "_pallas_stage_ok", lambda *a: True
-        )
         monkeypatch.setattr(pf_mod, "fir_decimate_pallas", boom)
-        try:
-            lfp = LFProc(spool(str(d)).sort("time").update())
-            lfp.update_processing_parameter(
-                output_sample_interval=1.0,
-                process_patch_size=60,
-                edge_buff_size=10,
-            )
-            out = tmp_path / "out"
-            lfp.set_output_folder(str(out), delete_existing=True)
-            lfp.process_time_range(
-                np.datetime64("2023-03-22T00:00:00"),
-                np.datetime64("2023-03-22T00:02:00"),
-            )
-        finally:
-            os.environ.pop("TPUDAS_PALLAS_IMPL", None)
-            fir_mod._layout_for.cache_clear()
-            fir_mod._clear_cascade_caches()
-        assert not lfp._pallas_ok
-        assert lfp.engine_counts["cascade-pallas"] == 0
-        assert lfp.engine_counts["cascade-xla"] == sum(
-            lfp.engine_counts.values()
-        )
-        assert len(list(out.iterdir())) > 0
-        assert "falling back to the XLA" in capsys.readouterr().out
+        with pytest.raises(RuntimeError, match="mosaic compile failure"):
+            self._run_batch(tmp_path)
+        assert not list((tmp_path / "out").iterdir())
 
+    def test_lfproc_silently_wrong_pallas_numbers_raise(
+        self, tmp_path, monkeypatch, forced_pallas
+    ):
+        """A miscompile that RETURNS wrong numbers is caught by the
+        first-window cross-check against the XLA formulation and
+        raised; nothing is written."""
+        import tpudas.ops.pallas_fir as pf_mod
+        from tpudas.proc.lfproc import PallasVerificationError
 
-    @pytest.mark.slow
-    def test_lfproc_falls_back_to_v1_impl(self, tmp_path, monkeypatch,
-                                          capsys):
-        """When only the v2 kernel body fails, the engine continues on
-        the v1 implementation — still Pallas, no XLA downgrade."""
+        real = pf_mod.fir_decimate_pallas
+
+        def corrupt(x, hb, R, n_out, **kw):
+            return real(x, hb, R, n_out=n_out, **kw) * 1.7
+
+        monkeypatch.setattr(pf_mod, "fir_decimate_pallas", corrupt)
+        with pytest.raises(PallasVerificationError,
+                           match="pallas-vs-xla rel err"):
+            self._run_batch(tmp_path)
+        assert not list((tmp_path / "out").iterdir())
+
+    def test_stream_fused_pallas_failure_raises(self, tmp_path,
+                                                monkeypatch):
+        """The realtime stream's fused-pallas step failing raises out
+        of run_lowpass_realtime (the fault boundary classes it fatal);
+        the persisted carry has not advanced past the failed block."""
         import tpudas.ops.fir as fir_mod
         import tpudas.ops.pallas_fir as pf_mod
-        from tpudas import spool
-        from tpudas.proc.lfproc import LFProc
+        from tpudas.proc.streaming import run_lowpass_realtime
         from tpudas.testing import make_synthetic_spool
-        from tpudas.utils.logging import set_log_handler
 
-        d = tmp_path / "raw"
+        src = str(tmp_path / "src")
         make_synthetic_spool(
-            d, n_files=4, file_duration=30.0, fs=100.0, n_ch=6, noise=0.01
+            src, n_files=3, file_duration=30.0, fs=100.0, n_ch=6,
+            noise=0.01,
         )
 
         def boom(*a, **k):
-            raise RuntimeError("v2 body rejected (synthetic)")
+            raise RuntimeError("fused kernel rejected (synthetic)")
 
-        monkeypatch.delenv("TPUDAS_PALLAS_IMPL", raising=False)
-        fir_mod._layout_for.cache_clear()
+        real_resolve = fir_mod.resolve_stream_engine
+
+        def to_pallas(engine, plan=None, T=0, n_ch=0):
+            got = real_resolve(engine, plan, T, n_ch)
+            return "fused-pallas" if got == "fused-xla" else got
+
+        monkeypatch.setenv("TPUDAS_FUSED_MIN_ELEMS", "0")
+        monkeypatch.setattr(fir_mod, "resolve_stream_engine", to_pallas)
+        monkeypatch.setattr(pf_mod, "fused_cascade_pallas", boom)
         fir_mod._clear_cascade_caches()
-        monkeypatch.setattr(
-            fir_mod, "resolve_cascade_engine",
-            lambda e="auto": "pallas" if e == "auto" else e,
-        )
-        monkeypatch.setattr(fir_mod, "_pallas_stage_ok", lambda *a: True)
-        monkeypatch.setattr(pf_mod, "_kernel_body", boom)
-        events = []
-        set_log_handler(events.append)
+        out = str(tmp_path / "out")
         try:
-            lfp = LFProc(spool(str(d)).sort("time").update())
-            lfp.update_processing_parameter(
-                output_sample_interval=1.0,
-                process_patch_size=60,
-                edge_buff_size=10,
-            )
-            out = tmp_path / "out"
-            lfp.set_output_folder(str(out), delete_existing=True)
-            lfp.process_time_range(
-                np.datetime64("2023-03-22T00:00:00"),
-                np.datetime64("2023-03-22T00:02:00"),
-            )
+            with pytest.raises(RuntimeError, match="fused kernel rejected"):
+                run_lowpass_realtime(
+                    source=src, output_folder=out,
+                    start_time="2023-03-22T00:00:00",
+                    output_sample_interval=1.0, edge_buffer=8.0,
+                    process_patch_size=40, poll_interval=0.0,
+                    file_duration=0.0, sleep_fn=lambda _: None,
+                    stateful=True, engine="fused",
+                )
         finally:
-            os.environ.pop("TPUDAS_PALLAS_IMPL", None)
-            set_log_handler(None)
-            fir_mod._layout_for.cache_clear()
             fir_mod._clear_cascade_caches()
-        assert lfp._pallas_ok  # never downgraded to XLA
-        assert lfp.engine_counts["cascade-xla"] == 0
-        assert lfp.engine_counts["cascade-pallas"] == sum(
-            lfp.engine_counts.values()
-        )
-        impls = [e for e in events if e["event"] == "pallas_impl_fallback"]
-        assert len(impls) == 1 and impls[0]["impl"] == "v1"
-        assert "continuing on the v1" in capsys.readouterr().out
+        from tpudas.proc.stream import load_carry
+
+        assert load_carry(out).consumed == 0
 
 
 class TestLFProcEngines:

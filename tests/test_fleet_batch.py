@@ -383,7 +383,7 @@ class TestBatchGroupFormer:
         reg = MetricsRegistry()
         f = BatchGroupFormer()
         carry = SimpleNamespace(
-            kind="cascade", engine_req="auto", pallas_ok=False,
+            kind="cascade", engine_req="auto",
             d_ns=10_000_000_000, ratio=100, edge_in=800, order=4,
         )
         r = _fake_runner(carry=carry)

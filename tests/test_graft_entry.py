@@ -19,6 +19,6 @@ def test_entry_jits_and_runs():
 @pytest.mark.slow  # the round driver exercises this path on every run
 @pytest.mark.parametrize("n", [4, 8])
 def test_dryrun_multichip(n):
-    # no device-count gate: the dryrun spawns its own clean-env child
+    # no device-count gate: the dryrun spawns its own CPU child
     # with n virtual CPU devices, independent of this process's backend
     ge.dryrun_multichip(n)

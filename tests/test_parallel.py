@@ -23,6 +23,17 @@ def require_8_devices():
         pytest.skip("needs 8 virtual devices")
 
 
+def _assert_ulp_close(got, ref):
+    """Time-sharded and window-batched cascades trace a different
+    (batched or sharded) shape than the single-device run, and the
+    installed XLA CPU backend may then order the stage dot's sums
+    differently: agreement is a few f32 ulps of the output scale, not
+    bit equality."""
+    from tpudas.testing import max_ulps
+
+    assert max_ulps(got, ref) <= 4
+
+
 def _signal(T, C, fs, seed=0):
     rng = np.random.default_rng(seed)
     t = np.arange(T) / fs
@@ -186,8 +197,8 @@ class TestShardedCascade:
 
     def test_lfproc_window_dp_byte_equal(self, tmp_path):
         """LFProc with window_dp batches steady-state windows over the
-        mesh "time" axis and stays byte-identical to the single-device
-        serial run."""
+        mesh "time" axis and agrees with the single-device serial run
+        to a few ulps (the batched shape may sum in another order)."""
         from tpudas import spool
         from tpudas.proc.lfproc import LFProc
         from tpudas.testing import make_synthetic_spool
@@ -227,86 +238,63 @@ class TestShardedCascade:
         batches = [e for e in events if e["event"] == "window_dp_batch"]
         assert batches, "no DP batch actually ran"
         assert sum(e["windows"] for e in batches) >= 2
-        assert np.array_equal(results["serial"], results["dp"])
+        _assert_ulp_close(results["dp"], results["serial"])
 
-    def test_lfproc_window_dp_failure_latches_off(self, tmp_path,
-                                                  monkeypatch):
-        """One batch-compute failure disables window_dp for the rest
-        of the run (no doomed stack transfer per batch) while the
-        per-window path completes the work."""
-        import tpudas.parallel.batch as batch_mod
+    @staticmethod
+    def _run_dp(tmp_path, n_files=6):
         from tpudas import spool
         from tpudas.proc.lfproc import LFProc
         from tpudas.testing import make_synthetic_spool
-        from tpudas.utils.logging import set_log_handler
 
         d = tmp_path / "raw"
         make_synthetic_spool(
-            d, n_files=6, file_duration=30.0, fs=100.0, n_ch=6, noise=0.01
+            d, n_files=n_files, file_duration=30.0, fs=100.0, n_ch=6,
+            noise=0.01,
         )
+        lfp = LFProc(
+            spool(str(d)).sort("time").update(),
+            mesh=make_mesh(8, time_shards=2),
+        )
+        lfp.update_processing_parameter(
+            output_sample_interval=1.0,
+            process_patch_size=60,
+            edge_buff_size=10,
+            window_dp=True,
+        )
+        lfp.set_output_folder(str(tmp_path / "out"), delete_existing=True)
+        lfp.process_time_range(
+            np.datetime64("2023-03-22T00:00:00"),
+            np.datetime64("2023-03-22T00:03:00"),
+        )
+        return lfp
+
+    def test_lfproc_window_dp_failure_raises(self, tmp_path, monkeypatch):
+        """A window-DP batch-compute failure raises out of the run: it
+        is not absorbed by re-running the batch per window."""
+        import tpudas.parallel.batch as batch_mod
 
         def boom(*a, **k):
             raise RuntimeError("batch compute failure (synthetic)")
 
         monkeypatch.setattr(batch_mod, "batched_cascade_decimate", boom)
-        events = []
-        set_log_handler(events.append)
-        try:
-            lfp = LFProc(
-                spool(str(d)).sort("time").update(),
-                mesh=make_mesh(8, time_shards=2),
-            )
-            lfp.update_processing_parameter(
-                output_sample_interval=1.0,
-                process_patch_size=60,
-                edge_buff_size=10,
-                window_dp=True,
-            )
-            out = tmp_path / "out"
-            lfp.set_output_folder(str(out), delete_existing=True)
-            lfp.process_time_range(
-                np.datetime64("2023-03-22T00:00:00"),
-                np.datetime64("2023-03-22T00:03:00"),
-            )
-        finally:
-            set_log_handler(None)
-        assert not lfp._window_dp_ok
-        falls = [e for e in events if e["event"] == "window_dp_fallback"]
-        assert len(falls) == 1, falls  # latched after the first failure
-        assert sum(lfp.engine_counts.values()) == 4  # all windows done
-        assert len(list(out.iterdir())) == 4
+        with pytest.raises(RuntimeError, match="batch compute failure"):
+            self._run_dp(tmp_path)
 
-    @pytest.mark.slow  # ~70 s: two full LFProc runs on the mesh
-    def test_lfproc_window_dp_crosscheck_catches_silent_corruption(
+    def test_lfproc_window_dp_crosscheck_failure_raises(
         self, tmp_path, monkeypatch
     ):
-        """A batched-lowering miscompile that RETURNS wrong numbers is
-        caught by the first-batch cross-check; the batch resolves
-        per-window (whose own chain lands on XLA), window-DP batching
-        itself stays enabled and later batches run under XLA — and the
-        emitted output is byte-equal to a serial run."""
+        """A batched Pallas lowering that RETURNS wrong numbers fails
+        the first-batch cross-check against the XLA formulation, and
+        the run raises."""
         import tpudas.ops.fir as fir_mod
         import tpudas.ops.pallas_fir as pf_mod
-        from tpudas import spool
-        from tpudas.proc.lfproc import LFProc
-        from tpudas.testing import make_synthetic_spool
-        from tpudas.utils.logging import set_log_handler
-
-        d = tmp_path / "raw"
-        # 8 files / 4 min -> five same-key interior windows: one batch
-        # fails the cross-check, and at least one LATER batch must
-        # still run (on XLA) to prove batching was not latched off
-        make_synthetic_spool(
-            d, n_files=8, file_duration=30.0, fs=100.0, n_ch=6, noise=0.01
-        )
+        from tpudas.proc.lfproc import PallasVerificationError
 
         real = pf_mod.fir_decimate_pallas
 
         def corrupt(x, hb, R, n_out, **kw):
             return real(x, hb, R, n_out=n_out, **kw) * 1.7
 
-        monkeypatch.delenv("TPUDAS_PALLAS_IMPL", raising=False)
-        fir_mod._layout_for.cache_clear()
         fir_mod._clear_cascade_caches()
         monkeypatch.setattr(
             fir_mod, "resolve_cascade_engine",
@@ -314,52 +302,12 @@ class TestShardedCascade:
         )
         monkeypatch.setattr(fir_mod, "_pallas_stage_ok", lambda *a: True)
         monkeypatch.setattr(pf_mod, "fir_decimate_pallas", corrupt)
-        events = []
-        set_log_handler(events.append)
         try:
-            results = {}
-            for label, mesh, dp in (
-                ("dp", make_mesh(8, time_shards=2), True),
-                ("serial", None, False),
-            ):
-                lfp = LFProc(spool(str(d)).sort("time").update(), mesh=mesh)
-                lfp.update_processing_parameter(
-                    output_sample_interval=1.0,
-                    process_patch_size=60,
-                    edge_buff_size=10,
-                    window_dp=dp,
-                )
-                out = tmp_path / f"out_{label}"
-                lfp.set_output_folder(str(out), delete_existing=True)
-                lfp.process_time_range(
-                    np.datetime64("2023-03-22T00:00:00"),
-                    np.datetime64("2023-03-22T00:04:00"),
-                )
-                results[label] = (
-                    spool(str(out)).update().chunk(time=None)[0].host_data()
-                )
-                if dp:
-                    assert lfp._window_dp_ok  # batching NOT latched off
-                    assert not lfp._pallas_ok  # the engine was
-                    assert lfp.engine_counts["cascade-pallas"] == 0
+            with pytest.raises(PallasVerificationError,
+                               match="pallas-vs-xla rel err"):
+                self._run_dp(tmp_path)
         finally:
-            os.environ.pop("TPUDAS_PALLAS_IMPL", None)
-            set_log_handler(None)
-            fir_mod._layout_for.cache_clear()
             fir_mod._clear_cascade_caches()
-        fails = [
-            e for e in events if e["event"] == "window_dp_crosscheck_fail"
-        ]
-        assert len(fails) == 1, fails
-        assert "pallas-vs-xla rel err" in fails[0]["error"]
-        # batching continued AFTER the failure, on the XLA engine
-        later = [
-            e for e in events
-            if e["event"] == "window_dp_batch"
-            and e["engine"] == "cascade-xla"
-        ]
-        assert later, "no XLA-engine batch ran after the cross-check"
-        assert np.array_equal(results["dp"], results["serial"])
 
     def test_window_dp_custom_single_axis_mesh(self):
         """A 1-axis DP mesh (no channel axis) leaves channels
@@ -403,8 +351,8 @@ class TestShardedCascade:
 
     def test_quantized_bit_equal_to_single_device(self):
         """Raw int16 windows shard undecoded (half the ICI halo bytes);
-        the result matches the single-device quantized cascade bit for
-        bit, which itself matches decode-then-cascade."""
+        the result matches the single-device quantized cascade to a
+        few ulps."""
         from tpudas.ops.fir import cascade_decimate
         from tpudas.parallel.pipeline import sharded_cascade_decimate
 
@@ -421,7 +369,7 @@ class TestShardedCascade:
             mesh, q, plan, phase, n_out, qscale=s
         )
         assert out is not None
-        assert np.array_equal(np.asarray(out), ref)
+        _assert_ulp_close(np.asarray(out), ref)
 
 
 class TestLFProcMesh:
@@ -582,13 +530,12 @@ class TestHaloExchange:
     def test_exchange_matches_padded_reference(self):
         from jax.sharding import PartitionSpec as P
 
-        from tpudas.parallel.compat import shard_map
         from tpudas.parallel.halo import exchange_halo_time
 
         mesh = make_mesh(8, time_shards=4)
         T, C, halo = 64, 4, 5
         x = np.arange(T * C, dtype=np.float32).reshape(T, C)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda b: exchange_halo_time(b, halo, n_shards=4),
             mesh=mesh,
             in_specs=P("time", "ch"),
@@ -614,13 +561,12 @@ class TestHaloExchange:
     def test_one_sided_exchange_halves_the_extension(self):
         from jax.sharding import PartitionSpec as P
 
-        from tpudas.parallel.compat import shard_map
         from tpudas.parallel.halo import exchange_halo_time
 
         mesh = make_mesh(8, time_shards=4)
         T, C, halo = 64, 2, 4
         x = np.arange(T * C, dtype=np.float32).reshape(T, C)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda b: exchange_halo_time(
                 b, halo, n_shards=4, left=False
             ),
@@ -738,67 +684,6 @@ class TestPadMaskLayout:
         }
         assert series[(("direction", "place"),)] == x.nbytes
         assert series[(("direction", "gather"),)] == x.nbytes
-
-
-class TestShardMapCompat:
-    """tpudas.parallel.compat is the one blessed shard_map entrypoint;
-    both replication-keyword spellings stay covered on any jax."""
-
-    def test_rep_kwargs_both_spellings(self):
-        from tpudas.parallel.compat import _rep_kwargs
-
-        assert _rep_kwargs({"check_vma": None}, False) == {
-            "check_vma": False
-        }
-        assert _rep_kwargs({"check_rep": None}, False) == {
-            "check_rep": False
-        }
-        assert _rep_kwargs({"check_vma": None, "check_rep": None}, True) == {
-            "check_vma": True
-        }
-        assert _rep_kwargs({}, True) == {}
-
-    def test_wrapper_runs_on_installed_jax(self):
-        from jax.sharding import PartitionSpec as P
-
-        from tpudas.parallel.compat import shard_map
-
-        mesh = make_mesh(4)
-        fn = shard_map(
-            lambda b: b * jax.lax.axis_index("ch").astype(jnp.float32),
-            mesh=mesh,
-            in_specs=P(None, "ch"),
-            out_specs=P(None, "ch"),
-            check_vma=False,
-        )
-        out = np.asarray(jax.jit(fn)(np.ones((2, 8), np.float32)))
-        ref = np.repeat(np.arange(4, dtype=np.float32), 2)[None, :]
-        assert np.array_equal(out, np.broadcast_to(ref, (2, 8)))
-
-    def test_compat_is_the_only_entrypoint(self):
-        """No tpudas module may import shard_map except the compat
-        shim (the version-skew surface must stay one file wide)."""
-        import re
-
-        root = os.path.join(os.path.dirname(__file__), "..", "tpudas")
-        offenders = []
-        pat = re.compile(
-            r"from\s+jax(\.experimental)?(\.shard_map)?\s+import"
-            r"[^\n]*\bshard_map\b|jax\.experimental\.shard_map"
-        )
-        for dirpath, _dirs, files in os.walk(root):
-            for name in files:
-                if not name.endswith(".py"):
-                    continue
-                path = os.path.join(dirpath, name)
-                if os.path.basename(path) == "compat.py":
-                    continue
-                with open(path) as fh:
-                    if pat.search(fh.read()):
-                        offenders.append(os.path.relpath(path, root))
-        assert not offenders, (
-            f"import shard_map via tpudas.parallel.compat: {offenders}"
-        )
 
 
 class TestShardedStreamOps:

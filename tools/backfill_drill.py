@@ -131,7 +131,7 @@ def _spawn(root, worker_id, fault="", log_fh=None, settle=0.02):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.setdefault(
-        "TPUDAS_COMPILE_CACHE",
+        "JAX_COMPILATION_CACHE_DIR",
         os.path.join(os.path.dirname(root), "xla_cache"),
     )
     proc = subprocess.Popen(
@@ -439,7 +439,7 @@ def _spawn_store(url, prefix, scratch_root, ready_dir, worker_id,
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.setdefault(
-        "TPUDAS_COMPILE_CACHE",
+        "JAX_COMPILATION_CACHE_DIR",
         os.path.join(os.path.dirname(scratch_root), "xla_cache"),
     )
     return subprocess.Popen(

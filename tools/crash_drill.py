@@ -296,7 +296,7 @@ def _run_cycle(src, out, engine, kill_after, log_fh=None,
     # the cold calibration cycle every worker warm-starts, so kills
     # land in real processing/write windows instead of jit compiles
     env.setdefault(
-        "TPUDAS_COMPILE_CACHE",
+        "JAX_COMPILATION_CACHE_DIR",
         os.path.join(os.path.dirname(out), "xla_cache"),
     )
     argv = (

@@ -562,7 +562,7 @@ def main(argv=None) -> int:
     }
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.pop("TPUDAS_COMPILE_CACHE", None)  # cold per child, honestly
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"  # cold per child
     n_list = [int(x) for x in args.streams.split(",") if x]
     legs = {"0": (False,), "1": (True,), "ab": (False, True)}[
         args.batched

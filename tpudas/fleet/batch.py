@@ -88,7 +88,6 @@ class BatchGroupFormer:
             id(runner),
             carry.kind,
             carry.engine_req,
-            bool(carry.pallas_ok),
             carry.d_ns,
             carry.ratio,
             carry.edge_in,
@@ -129,7 +128,6 @@ class BatchGroupFormer:
                 carry.edge_in,
                 carry.order,
                 carry.engine_req,
-                bool(carry.pallas_ok),
             )
         self._memo[str(stream_id)] = (token, sig)
         return sig

@@ -212,11 +212,11 @@ class FleetEngine:
         # fleet run cannot grow it without limit (the bench reads it)
         self.service_log = _collections.deque(maxlen=_SERVICE_LOG_MAX)
         # N same-geometry arrays in one process share jax's in-process
-        # jit cache by construction; honor TPUDAS_COMPILE_CACHE so
-        # fleet restarts warm-start across processes too
-        from tpudas.utils.compile_cache import maybe_enable_from_env
+        # jit cache by construction; the persistent cache makes fleet
+        # restarts warm-start across processes too
+        from tpudas.utils.compile_cache import enable_compile_cache
 
-        maybe_enable_from_env()
+        enable_compile_cache()
         reg = get_registry()
         self.streams: dict = {}
         for spec in specs:

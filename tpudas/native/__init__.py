@@ -1,7 +1,8 @@
 """Native (C++) ingest runtime, bound via ctypes.
 
 ``load_streamio()`` compiles ``streamio.cpp`` on first use (g++ -O3,
-cached next to the source, keyed on source mtime) and returns a ctypes
+cached next to the source, keyed on a hash of the source, so a binary
+built from other source is never loaded) and returns a ctypes
 handle, or ``None`` when no toolchain is available / compilation fails /
 ``TPUDAS_NO_NATIVE=1``. Callers in :mod:`tpudas.io.tdas` fall back to a
 pure-numpy implementation of the same format, so the framework is fully
@@ -12,29 +13,38 @@ runtime, not a hard dependency.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 
 _SRC = os.path.join(os.path.dirname(__file__), "streamio.cpp")
-_LIB = os.path.join(os.path.dirname(__file__), "libstreamio.so")
 
 _lock = threading.Lock()
 _cached: tuple[bool, ctypes.CDLL | None] | None = None
 
 
-def _compile() -> bool:
+def lib_path() -> str | None:
+    """Where the library built from the current ``streamio.cpp`` lives
+    (``libstreamio.<sha256 prefix>.so``), or None without a source."""
     try:
-        src_mtime = os.path.getmtime(_SRC)
+        with open(_SRC, "rb") as fh:
+            digest = hashlib.sha256(fh.read()).hexdigest()[:16]
     except OSError:
-        return False
-    if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= src_mtime:
+        return None
+    return os.path.join(
+        os.path.dirname(_SRC), f"libstreamio.{digest}.so"
+    )
+
+
+def _compile(lib: str) -> bool:
+    if os.path.exists(lib):
         return True
     # compile to a process-unique temp in the same directory: concurrent
     # processes (pytest-xdist, parallel streaming jobs) would otherwise
     # interleave g++ writes on one shared inode and os.replace could
     # publish a corrupted .so that then gets cached for process lifetime
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
+    tmp = f"{lib}.{os.getpid()}.tmp"
     cmd = [
         "g++",
         "-O3",
@@ -56,7 +66,7 @@ def _compile() -> bool:
         if proc.returncode != 0:
             return False
         try:
-            os.replace(tmp, _LIB)
+            os.replace(tmp, lib)
         except OSError:
             return False
         return True
@@ -114,9 +124,10 @@ def load_streamio() -> ctypes.CDLL | None:
             _cached = (False, None)
             return None
         lib = None
-        if _compile():
+        path = lib_path()
+        if path is not None and _compile(path):
             try:
-                lib = _bind(ctypes.CDLL(_LIB))
+                lib = _bind(ctypes.CDLL(path))
             except OSError:
                 lib = None
         _cached = (lib is not None, lib)

@@ -165,11 +165,9 @@ def _build_fft_stream_fn(T, rows_carry, n_ch, d_sec, low, high, order,
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
-        from tpudas.parallel.compat import shard_map
-
         spec = P(None, ch_axis)
         in_specs = (spec, spec, P()) if quantized else (spec, spec)
-        body = shard_map(
+        body = jax.shard_map(
             fn, mesh=mesh, in_specs=in_specs,
             out_specs=(spec, spec), check_vma=False,
         )
@@ -319,11 +317,9 @@ def _build_stacked_fft_fn(T, rows_carry, widths, d_sec, low, high, order,
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
-        from tpudas.parallel.compat import shard_map
-
         Cp = C + (-C % int(mesh.shape[ch_axis]))
         spec = P(None, ch_axis)
-        body = shard_map(
+        body = jax.shard_map(
             core, mesh=mesh, in_specs=(spec, spec),
             out_specs=(spec, spec), check_vma=False,
         )

@@ -10,7 +10,7 @@ positions — the op the reference executes as full-rate ``sosfiltfilt``
 + decimating ``interpolate`` (lf_das.py:223-225) and XLA executes as
 B shifted matmuls with B full HBM passes.
 
-Design (v2, informed by on-chip measurement — see PERF.md §4):
+Design (v2, informed by round-4 chip measurements — PERF.md §5):
 
 - **MXU banded matmul, not VPU shifted adds.**  For an SB-frame output
   sub-block the FIR is one dot ``Y = A @ X`` with
@@ -125,8 +125,7 @@ def _mosaic_knobs():
     - TPUDAS_PALLAS_VMEM_MB: vmem_limit_bytes override, in MiB —
       larger double-buffering headroom for big-block geometries.
 
-    Defaults leave everything unset: identical behavior/lowering to
-    the kernel that passed chip_check (chip_r05/chip_check.log).
+    Defaults leave everything unset (Mosaic's own choices).
     """
     sems_env = os.environ.get("TPUDAS_PALLAS_DIMSEM", "").strip()
     grid_order = os.environ.get("TPUDAS_PALLAS_GRID", "kc").strip() or "kc"
@@ -152,12 +151,7 @@ def _mosaic_knobs():
         cp_kwargs["vmem_limit_bytes"] = vmem_mb * 2**20
     call_kwargs = {}
     if cp_kwargs:
-        # renamed TPUCompilerParams -> CompilerParams across jax
-        # versions; accept either spelling
-        params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams"
-        )
-        call_kwargs["compiler_params"] = params_cls(**cp_kwargs)
+        call_kwargs["compiler_params"] = pltpu.CompilerParams(**cp_kwargs)
     return grid_order, call_kwargs
 
 
@@ -244,11 +238,9 @@ def _band_matrix(taps: tuple, R: int, SB: int, rows: int) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# v1 implementation (VPU shifted multiply-reduce): the kernel behind the
-# proven 29.06 G ch-samp/s on-chip record (PERF.md §3).  Kept selectable
-# via TPUDAS_PALLAS_IMPL=v1 — and as the bench's automatic middle
-# fallback — until the v2 MXU kernel has been validated by Mosaic on
-# real hardware (it has only interpret-mode coverage; PERF.md §5).
+# v1 implementation (VPU shifted multiply-reduce), the round-4 kernel.
+# Selectable via TPUDAS_PALLAS_IMPL=v1 for A/B runs on the chip; it is
+# never chosen automatically (a failing kernel raises).
 
 
 def _kernel_body_v1(B, KB, CB):
@@ -345,7 +337,7 @@ def fir_decimate_pallas(
     executable serves every scale.
 
     ``TPUDAS_PALLAS_IMPL=v1`` selects the previous VPU formulation
-    (the proven-on-hardware kernel; see the v1 section below).
+    (see the v1 section below).
     """
     if os.environ.get("TPUDAS_PALLAS_IMPL", "v2") == "v1":
         return _fir_decimate_pallas_v1(x, hb, R, n_out, interpret)
@@ -447,11 +439,15 @@ def fir_decimate_pallas(
 # bf16 split): the per-stage work is ~B multiply-adds per input sample
 # and the fused kernel's DMA stream is ~R-times lighter than the
 # per-stage kernels' (input read once, decimated output only), so the
-# VPU-vs-MXU tradeoff of PERF.md §4 tilts back — the v2 MXU banded
+# VPU-vs-MXU tradeoff of the v2 notes above tilts back — the v2 MXU banded
 # matmul needed its arithmetic headroom to keep up with TWO full-rate
 # HBM streams per stage, which the fused kernel has eliminated.
-# Like v2 at its introduction, v3 has interpret-mode coverage here and
-# awaits Mosaic validation on silicon (PERF.md §5 protocol).
+# The final output is written as (time chunk, chunk_out, C) blocks: a
+# 2-D (chunk_out, CB) block would break Mosaic's 8x128 tiling rule for
+# the chunk sizes fused_chunk_outputs picks (6 at a 60 s / 1 Hz
+# block), while a block whose last two dims span the whole array's
+# last two dims is always legal.  tests/test_tpu_compile.py compiles
+# it for a described v5e.
 #
 # Tail alignment trick: stage i carries p_i trailing input rows
 # (tpudas.ops.fir.stream_carry_sizes — p_i is NOT generally a
@@ -633,7 +629,7 @@ def fused_cascade_pallas(
         ],
         out_specs=[
             pl.BlockSpec(
-                (int(chunk_out), CB), lambda c, t: (t, c),
+                (None, int(chunk_out), CB), lambda c, t: (t, 0, c),
                 memory_space=pltpu.VMEM,
             ),
             *[
@@ -645,7 +641,9 @@ def fused_cascade_pallas(
             ],
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((T // ratio, nc * CB), jnp.float32),
+            jax.ShapeDtypeStruct(
+                (nt, int(chunk_out), nc * CB), jnp.float32
+            ),
             *[
                 jax.ShapeDtypeStruct((p, nc * CB), jnp.float32)
                 for _i, _q, p in state
@@ -664,7 +662,8 @@ def fused_cascade_pallas(
         x.astype(jnp.float32),
         *[bufs[i] for i, _q, _p in state],
     )
-    y = outs[0][:, :C] if pad_c else outs[0]
+    y = outs[0].reshape(T // ratio, nc * CB)
+    y = y[:, :C] if pad_c else y
     new_tails = iter(outs[1:])
     new_bufs = []
     for i, b in enumerate(bufs):

@@ -39,8 +39,6 @@ def batched_rolling_mean(mesh, batch, w: int, s: int, batch_axis="ch"):
 def _build_batched_cascade_fn(
     plan, n_out, engine, mesh, batch_axis, ch_axis, quantized, knobs=()
 ):
-    from tpudas.parallel.compat import shard_map
-
     from tpudas.ops.fir import (
         _apply_cascade_stages,
         _blocked_taps,
@@ -69,7 +67,7 @@ def _build_batched_cascade_fn(
         in_specs = (spec,)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=spec,
             check_vma=False,
         )

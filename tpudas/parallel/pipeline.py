@@ -23,8 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tpudas.parallel.compat import shard_map
-
 from tpudas.ops.fftlen import next_tpu_fft_len
 from tpudas.ops.filter import fft_lowpass_response
 from tpudas.parallel.halo import exchange_halo_time
@@ -75,7 +73,7 @@ def sharded_lowpass_decimate(
     spec_2d = P(time_axis, ch_axis)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec_2d,),
         out_specs=spec_2d,
@@ -139,7 +137,7 @@ def _build_sharded_cascade_fn(
     )
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P(time_axis, ch_axis),

@@ -28,7 +28,6 @@ import functools
 import os
 import shutil
 import time
-import traceback
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
@@ -88,7 +87,7 @@ def resolve_gap_tolerance(correct=None, legacy=None):
     return legacy
 
 # first-window cross-check tolerance: the v2 kernel's 3-pass bf16 dot
-# splits land ~1e-5 from the f32 XLA formulation (PERF.md §4) and the
+# splits land ~1e-5 from the f32 XLA formulation (PERF.md §5) and the
 # cascade's design tolerance is 1e-4; a Mosaic miscompile produces
 # garbage, not 1e-3-level error, so 1e-3 separates the two cleanly
 _PALLAS_VERIFY_TOL = 1e-3
@@ -96,8 +95,7 @@ _PALLAS_VERIFY_TOL = 1e-3
 
 class PallasVerificationError(RuntimeError):
     """The Pallas kernel compiled but its first-window output disagrees
-    with the XLA formulation beyond tolerance — treated exactly like a
-    compile failure by the engine fallback chain."""
+    with the XLA formulation beyond tolerance; raised out of the run."""
 
 
 def _pallas_crosscheck(got, ref, what):
@@ -248,12 +246,12 @@ class LFProc:
     """
 
     def __init__(self, sp=None, mesh=None):
-        # TPUDAS_COMPILE_CACHE: persistent XLA compilation cache so a
-        # restarted deployment (or the next polling-round process)
-        # skips the first-window compile (tpudas.utils.compile_cache)
-        from tpudas.utils.compile_cache import maybe_enable_from_env
+        # persistent XLA compilation cache so a restarted deployment
+        # (or the next polling-round process) skips the first-window
+        # compile (placement rule: tpudas.utils.compile_cache)
+        from tpudas.utils.compile_cache import enable_compile_cache
 
-        maybe_enable_from_env()
+        enable_compile_cache()
         self._spool = sp
         self._para = self._default_process_parameters()
         self._output_folder = None
@@ -274,15 +272,10 @@ class LFProc:
         # the prefetch thread's window read, device = kernel dispatch
         # through host-side result sync, write = HDF5 output
         self.timings = {"assemble_s": 0.0, "device_s": 0.0, "write_s": 0.0}
-        # flips False permanently if the Pallas fast path fails to
-        # compile on this backend (engine falls back to the XLA
-        # cascade — same numerics; see _process_window).  _pallas_proven
-        # records the window shapes whose pallas compile has executed:
-        # jit caches per shape, so a tail window with a fresh n_out is
-        # a fresh compile and still deserves the fallback — but a
-        # failure on an already-proven shape is not a compile problem
-        # and propagates.
-        self._pallas_ok = True
+        # window shapes whose Pallas output has passed the first-window
+        # cross-check against the XLA formulation (jit caches per
+        # shape, so each new shape is checked once).  A Pallas failure
+        # of any kind raises: nothing here swaps in another engine.
         self._pallas_proven = set()
         # emission listeners: each called with every output patch
         # AFTER its HDF5 write (the realtime driver feeds the
@@ -303,16 +296,9 @@ class LFProc:
         self._pallas_verify = (
             os.environ.get("TPUDAS_PALLAS_VERIFY", "1") != "0"
         )
-        # latches False after a window-DP batch-compute failure: the
-        # rest of the run executes per-window instead of paying a
-        # doomed stack transfer on every batch
-        self._window_dp_ok = True
         self._run_origin_ns = None  # set per process_time_range run
         self._first_window_of_run = True
         self._dp_proven = set()  # DP keys whose batched kernel passed
-        self._dp_bad = set()  # (key, impl) pairs whose batched pallas
-        # run failed the first-batch cross-check (kept per-window while
-        # that implementation is the active one)
 
     # configuration ----------------------------------------------------
     def _default_process_parameters(self):
@@ -721,7 +707,6 @@ class LFProc:
 
         if (
             self._para.get("window_dp")
-            and self._window_dp_ok
             and self._mesh is not None
             and self._mesh.shape.get("time", 1) > 1
         ):
@@ -801,13 +786,6 @@ class LFProc:
             plan, phase, int(target_times.size), host.shape,
             str(host.dtype), qs,
         )
-        impl = os.environ.get("TPUDAS_PALLAS_IMPL", "v2")
-        if (key, impl) in self._dp_bad and self._pallas_ok:
-            # this key's batched pallas lowering failed the numeric
-            # cross-check under the CURRENT implementation: keep it
-            # per-window while that implementation is in play
-            # (batching resumes under a v1 auto-switch or XLA latch)
-            return None
         return {"key": key, "host": host, "plan": plan, "phase": phase,
                 "n_out": int(target_times.size), "qs": qs}
 
@@ -840,16 +818,12 @@ class LFProc:
             qs = infos[0]["qs"]
             stack = np.stack([i["host"] for i in infos])
             n_ch_local = -(-stack.shape[2] // mesh.shape["ch"])
-            # mirror the per-window engine request: a previous Pallas
-            # compile failure keeps DP batches on the XLA formulation
-            # instead of re-raising (and re-serializing) every batch
-            eng_req = "auto" if self._pallas_ok else "xla"
-            stages = stage_engines(plan, n_out, n_ch_local, eng_req)
+            stages = stage_engines(plan, n_out, n_ch_local, "auto")
             ran = "cascade-pallas" if "pallas" in stages else "cascade-xla"
             t0 = time.perf_counter()
             out = np.asarray(
                 batched_cascade_decimate(
-                    mesh, stack, plan, phase, n_out, engine=eng_req,
+                    mesh, stack, plan, phase, n_out, engine="auto",
                     batch_axis="time", ch_axis="ch", qscale=qs,
                 )
             )
@@ -865,15 +839,13 @@ class LFProc:
                 # window axis) than the per-window path, so it gets its
                 # own first-batch cross-check: window 0 of the batch vs
                 # the unbatched XLA formulation.  A mismatch raises
-                # into flush()'s handler, which degrades to the
-                # per-window path (whose own fallback chain then runs).
+                # PallasVerificationError out of the run.
                 from tpudas.ops.fir import cascade_decimate
 
                 # mesh=mesh: the reference must shard channels the same
                 # way the per-window path does, or window 0 of a wide
                 # (north-star-scale) config lands whole on one device
-                # and OOMs — which the generic handler would misread as
-                # a batch-compute failure
+                # and OOMs
                 ref = cascade_decimate(
                     stack[0], plan, phase, n_out, "xla", mesh=mesh,
                     qscale=qs,
@@ -891,52 +863,7 @@ class LFProc:
                 self._process_window(patch, emit_times, dt, corner, order)
                 pending.clear()
                 return
-            try:
-                out, ran, rows, t_dev = run_batch()
-            except PallasVerificationError as exc:
-                # only the pallas engine is invalidated, not batching:
-                # mark (key, impl) so this key is never re-batched
-                # under the implementation that just failed, then
-                # resolve the engine on the per-window path (its own
-                # v1→XLA chain).  Later batches still batch — under
-                # v1 after an auto-switch (re-verified on first batch)
-                # or under XLA after a full latch.
-                self._dp_bad.add((
-                    pending[0][2]["key"],
-                    os.environ.get("TPUDAS_PALLAS_IMPL", "v2"),
-                ))
-                print(
-                    "Warning: window-DP batch numerics failed "
-                    f"cross-check ({str(exc)[:120]}); resolving this "
-                    "batch per-window"
-                )
-                log_event(
-                    "window_dp_crosscheck_fail", error=str(exc)[:300]
-                )
-                for patch, emit_times, _ in pending:
-                    self._process_window(
-                        patch, emit_times, dt, corner, order
-                    )
-                pending.clear()
-                return
-            except Exception as exc:
-                # a batch-COMPUTE failure degrades to the per-window
-                # path, which has its own (shape-keyed) fallback — and
-                # latches window_dp off for the rest of the run, since
-                # retrying pays the doomed stack transfer per batch
-                self._window_dp_ok = False
-                print(
-                    "Warning: window-DP batch failed "
-                    f"({str(exc)[:120]}); per-window execution for "
-                    "the rest of the run"
-                )
-                log_event("window_dp_fallback", error=str(exc)[:300])
-                for patch, emit_times, _ in pending:
-                    self._process_window(
-                        patch, emit_times, dt, corner, order
-                    )
-                pending.clear()
-                return
+            out, ran, rows, t_dev = run_batch()
             log_event(
                 "window_dp_batch", windows=len(pending), engine=ran,
                 rows=rows, emitted=int(pending[0][2]["n_out"]),
@@ -963,12 +890,8 @@ class LFProc:
                 flush()
                 log_event("window_skipped_gap", index=i + 1)
                 continue
-            info = (
-                self._dp_window_info(
-                    window_patch, emit_times, dt, corner, order
-                )
-                if self._window_dp_ok  # mid-segment latch flip
-                else None
+            info = self._dp_window_info(
+                window_patch, emit_times, dt, corner, order
             )
             if info is None:
                 flush()
@@ -1166,10 +1089,6 @@ class LFProc:
                     align = None  # auto: fall back to the FFT engine
         mesh = self._mesh
         n_out = int(target_times.size)
-        # engine request honouring a previous in-process Pallas failure
-        # (self._pallas_ok): once the fast path has compile-failed on
-        # this backend it stays off for the rest of the run
-        eng_req = "auto" if self._pallas_ok else "xla"
         # which execution layout will this window take? decided up
         # front so the engine observability below reports exactly what
         # each device traces: under a mesh the Pallas size threshold
@@ -1186,11 +1105,10 @@ class LFProc:
             time_layout = sharded_cascade_layout(
                 mesh, plan, phase, n_out, int(host.shape[0]),
                 n_ch_local=-(-int(host.shape[1]) // mesh.shape["ch"]),
-                engine=eng_req,
+                engine="auto",
             )
         # which engine will this window run under? (config says
-        # "auto"/"cascade"; the count/event emitted AFTER execution is
-        # the ground truth, surviving the Pallas fallback below)
+        # "auto"/"cascade"; the layout decides per stage from shape)
         n_ch_decide = int(host.shape[1])
         if mesh is not None:
             n_ch_decide = -(-n_ch_decide // mesh.shape["ch"])
@@ -1198,7 +1116,7 @@ class LFProc:
             from tpudas.ops.fir import stage_engines
 
             n_out_decide = time_layout[0] if time_layout else n_out
-            stages = stage_engines(plan, n_out_decide, n_ch_decide, eng_req)
+            stages = stage_engines(plan, n_out_decide, n_ch_decide, "auto")
             ran = (
                 "cascade-pallas" if "pallas" in stages else "cascade-xla"
             )
@@ -1238,9 +1156,6 @@ class LFProc:
                 str(host.dtype),  # int16 vs f32 payloads compile apart
             )
 
-            ref_box = {}  # XLA reference, computed at most once per
-            # window and reused by the v1 retry and the final fallback
-
             def _run_checked(eng):
                 o = _run_cascade(eng)
                 if (
@@ -1250,15 +1165,12 @@ class LFProc:
                 ):
                     # first window of an unproven shape: cross-check
                     # the Pallas output against the XLA formulation on
-                    # the SAME window.  The fallback chain only fires
-                    # on raised exceptions; a Mosaic miscompile that
-                    # returns silently wrong numbers must not ship
-                    # through LFProc undetected.  Costs one extra XLA
-                    # run on the first window of each shape.
-                    if "ref" not in ref_box:
-                        ref_box["ref"] = np.asarray(_run_cascade("xla"))
+                    # the SAME window.  A Mosaic miscompile that returns
+                    # silently wrong numbers raises here instead of
+                    # shipping.  Costs one extra XLA run on the first
+                    # window of each shape.
                     rel = _pallas_crosscheck(
-                        o, ref_box["ref"], "first window"
+                        o, np.asarray(_run_cascade("xla")), "first window"
                     )
                     log_event(
                         "pallas_crosscheck", rel_err=rel,
@@ -1266,93 +1178,9 @@ class LFProc:
                     )
                 return o
 
-            try:
-                out = _run_checked(eng_req)
-                if ran == "cascade-pallas":
-                    self._pallas_proven.add(shape_key)
-            except Exception as exc:
-                # a compile failure of the Pallas fast path must not
-                # kill the run: try the v1 (proven-on-hardware) kernel
-                # implementation, then permanently fall back to the
-                # XLA formulation (same numerics) — and say so.  Only
-                # a not-yet-proven window shape qualifies — once the
-                # kernel has executed for this shape, a later failure
-                # is not a compile problem and must propagate.  Device
-                # (HBM) exhaustion also propagates — XLA would OOM on
-                # the same window — but VMEM exhaustion is exactly a
-                # kernel-formulation failure the fallback absorbs (the
-                # XLA path tiles through HBM instead of VMEM).
-                msg = str(exc)
-                # the blanket except is deliberate (compile failures
-                # surface as many exception types across jax versions)
-                # but must stay diagnosable: the full traceback goes to
-                # the event log so a masked non-Pallas bug can still be
-                # found
-                log_event(
-                    "pallas_error_detail",
-                    traceback=traceback.format_exc(),
-                )
-                hbm_oom = (
-                    "RESOURCE_EXHAUSTED" in msg
-                    and "vmem" not in msg.lower()
-                )
-                if (
-                    ran != "cascade-pallas"
-                    or shape_key in self._pallas_proven
-                    or hbm_oom
-                ):
-                    raise
-                out = None
-                # an EXPLICIT TPUDAS_PALLAS_IMPL is the operator's
-                # choice (either value) and is never overridden; only
-                # the unset default may auto-switch — process-wide by
-                # design, since the v2 kernel is broken on this
-                # backend for every in-process user alike
-                if "TPUDAS_PALLAS_IMPL" not in os.environ:
-                    from tpudas.ops.fir import _clear_cascade_caches
-
-                    os.environ["TPUDAS_PALLAS_IMPL"] = "v1"
-                    _clear_cascade_caches()
-                    # v1 is a different lowering: everything proven
-                    # under v2 must re-verify (and a v1 failure on a
-                    # previously-proven shape must still reach the XLA
-                    # fallback instead of propagating)
-                    self._pallas_proven.clear()
-                    self._dp_proven.clear()
-                    try:
-                        out = _run_checked(eng_req)
-                        self._pallas_proven.add(shape_key)
-                        print(
-                            "Warning: Pallas v2 kernel failed "
-                            f"({msg[:120]}); continuing on the v1 "
-                            "kernel implementation"
-                        )
-                        log_event(
-                            "pallas_impl_fallback", impl="v1",
-                            error=msg[:300],
-                        )
-                    except Exception as exc2:
-                        msg += " | v1: " + str(exc2)[:200]
-                        # v1 just failed too: leaving the env var set
-                        # would route other in-process callers of the
-                        # kernel to a known-failing implementation
-                        os.environ.pop("TPUDAS_PALLAS_IMPL", None)
-                        _clear_cascade_caches()
-                        out = None
-                if out is None:
-                    self._pallas_ok = False
-                    print(
-                        "Warning: Pallas kernel failed on this backend "
-                        f"({msg[:120]}); falling back to the XLA "
-                        "cascade for the rest of the run"
-                    )
-                    log_event("pallas_fallback", error=msg[:300])
-                    ran = "cascade-xla"
-                    # a verification failure already computed the XLA
-                    # result for this window — emit it, don't recompute
-                    out = ref_box.get("ref")
-                    if out is None:
-                        out = _run_cascade("xla")
+            out = _run_checked("auto")
+            if ran == "cascade-pallas":
+                self._pallas_proven.add(shape_key)
         else:
             idx, w = interp_indices_weights(taxis, target_times)
             data = host32
@@ -1415,8 +1243,7 @@ class LFProc:
         window-DP driver (which computes ``out`` in a batch)."""
         ax = window_patch.axis_of("time")
         mesh = self._mesh
-        # ground truth of what ACTUALLY ran (post-execution: survives
-        # the Pallas fallback above)
+        # ground truth of what ran, counted after execution
         self.engine_counts[ran] += 1
         get_registry().counter(
             "tpudas_windows_total",

@@ -6,7 +6,7 @@ the processing working set — fits a memory budget:
 safety``. On TPU the same closed form applies with the budget set to
 usable HBM (about 14000 MB on a 16 GB v5e chip); the default
 ``processing_factor`` stays at the reference's 5 — the measured
-peak-HBM-per-window table in PERF.md §7 (``tools/hbm_probe.py``)
+peak-HBM-per-window question in PERF.md §7 (``tools/hbm_probe.py``)
 validates that the cascade's working set stays under it in float32.
 
 Distinct from this HBM model are LFProc's two HOST-side byte budgets,

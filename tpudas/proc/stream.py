@@ -125,10 +125,6 @@ class StreamCarry:
     last_emit_ns: int | None = None  # newest output written (reconcile key)
     consumed: int = 0  # full-rate samples fed through the filter
     emitted: int = 0  # output samples written
-    # latches False after a Pallas stream-step failure; lives on the
-    # carry (not the per-round LFProc) so a failing kernel is not
-    # re-dispatched every polling round or process restart
-    pallas_ok: bool = True
 
     def _meta(self) -> dict:
         return {
@@ -156,7 +152,6 @@ class StreamCarry:
             "last_emit_ns": _opt_int(self.last_emit_ns),
             "consumed": int(self.consumed),
             "emitted": int(self.emitted),
-            "pallas_ok": bool(self.pallas_ok),
         }
 
 
@@ -280,7 +275,6 @@ def _parse_carry(path: str) -> StreamCarry:
             last_emit_ns=meta["last_emit_ns"],
             consumed=meta["consumed"],
             emitted=meta["emitted"],
-            pallas_ok=bool(meta.get("pallas_ok", True)),
         )
 
 
@@ -927,15 +921,10 @@ def _consume_cascade(lfp, carry: StreamCarry, patch, new, qs,
     mesh = _stream_mesh(lfp)
     pool, pool_qs = _pool_with_residual(carry, new, qs)
     usable = pool.shape[0] - pool.shape[0] % carry.ratio
-    pallas_ok = lfp._pallas_ok and carry.pallas_ok
-    if carry.engine_req == "fused":
-        # the fused selector: fused-pallas on TPU / fused-xla
-        # elsewhere, per-stage chain below the measured size
-        # threshold (tpudas.ops.fir.resolve_stream_engine); a latched
-        # Pallas failure forces the scan formulation
-        eng_req = "fused" if pallas_ok else "fused-xla"
-    else:
-        eng_req = "auto" if pallas_ok else "xla"
+    # the fused selector: fused-pallas on TPU / fused-xla elsewhere,
+    # per-stage chain below the measured size threshold or where the
+    # kernel's VMEM does not fit (tpudas.ops.fir.resolve_stream_engine)
+    eng_req = "fused" if carry.engine_req == "fused" else "auto"
     # engine thresholds see what one device actually traces: the LOCAL
     # (padded) channel count under a mesh
     n_ch_eng = (
@@ -954,91 +943,45 @@ def _consume_cascade(lfp, carry: StreamCarry, patch, new, qs,
             ran = stages[0]
         else:
             ran = "cascade-pallas" if "pallas" in stages else "cascade-xla"
-        if ran.endswith("pallas"):
-            # Pallas blocks keep the fully synchronous shape: the
-            # fallback chain needs the failure surfaced AT this block
-            # while the pre-dispatch carry snapshot is still valid —
-            # flush pending deferred blocks first so emission order
-            # is preserved.  The stream step donates the carry on
-            # accelerators, so the retry must not reuse buffers the
-            # failed dispatch already consumed.
-            pipe.flush()
-            backup = tuple(np.asarray(b) for b in carry.bufs)
-            t0 = time.perf_counter()
-            try:
-                y, bufs = cascade_decimate_stream(
-                    blk, carry.bufs, plan, eng_req, mesh=mesh,
-                    qscale=pool_qs,
-                )
-            except Exception as exc:
-                # mirror the batch path's Pallas resilience: a
-                # fast-path failure degrades to the XLA formulation
-                # (fused scan for a fused stream) for the rest of the
-                # run instead of killing the stream
-                fb = "fused-xla" if ran == "fused-pallas" else "xla"
-                print(
-                    "Warning: Pallas kernel failed in the stream path "
-                    f"({str(exc)[:120]}); falling back to {fb}"
-                )
-                log_event("stream_pallas_fallback", error=str(exc)[:300])
-                lfp._pallas_ok = False
-                carry.pallas_ok = False  # persists across restarts
-                eng_req = fb
-                ran = "cascade-xla" if fb == "xla" else fb
-                y, bufs = cascade_decimate_stream(
-                    blk, backup, plan, eng_req, mesh=mesh,
-                    qscale=pool_qs,
-                )
-            y = np.asarray(y)
-            t_dev = time.perf_counter() - t0
+        # deferred-sync pipeline: dispatch now (JAX queues the
+        # compute; the next block's pad-and-place overlaps it),
+        # sync + emit when the block reaches the pipeline head —
+        # same order, same math, just overlapped wall clock
+        t0 = time.perf_counter()
+        bx = getattr(lfp, "_batch_executor", None)
+        if bx is not None and mesh is None and not ran.endswith("pallas"):
+            # ragged-batched fleet service (ISSUE 16): rendezvous
+            # with the other batch-group members so co-shaped
+            # blocks stack into ONE device program.  The engine is
+            # resolved HERE at this stream's own width (`ran` is
+            # already the solo decision), so stacking never flips
+            # a threshold; byte-identical either way.
+            y_dev, bufs = bx.cascade_step(
+                blk, carry.bufs, plan,
+                ran if ran == "fused-xla" else "xla",
+                qscale=pool_qs,
+            )
+        else:
+            y_dev, bufs = cascade_decimate_stream(
+                blk, carry.bufs, plan, eng_req, mesh=mesh,
+                qscale=pool_qs,
+            )
+        t_disp = time.perf_counter() - t0
+        carry.bufs = bufs
+
+        def _flush(y_dev=y_dev, rows=rows, ran=ran, t_disp=t_disp):
+            t1 = time.perf_counter()
+            y = np.asarray(y_dev)
+            t_dev = t_disp + time.perf_counter() - t1
             lfp.timings["device_s"] += t_dev
             _count_block(rows, ran, t_dev)
-            carry.bufs = bufs
             carry.consumed += rows
             s = min(carry.skip_left, y.shape[0])
             carry.skip_left -= s
             _emit(lfp, carry, patch, y[s:], rows=rows, ran=ran,
                   t_dev=t_dev)
-        else:
-            # deferred-sync pipeline: dispatch now (JAX queues the
-            # compute; the next block's pad-and-place overlaps it),
-            # sync + emit when the block reaches the pipeline head —
-            # same order, same math, just overlapped wall clock
-            t0 = time.perf_counter()
-            bx = getattr(lfp, "_batch_executor", None)
-            if bx is not None and mesh is None:
-                # ragged-batched fleet service (ISSUE 16): rendezvous
-                # with the other batch-group members so co-shaped
-                # blocks stack into ONE device program.  The engine is
-                # resolved HERE at this stream's own width (`ran` is
-                # already the solo decision), so stacking never flips
-                # a threshold; byte-identical either way.
-                y_dev, bufs = bx.cascade_step(
-                    blk, carry.bufs, plan,
-                    ran if ran == "fused-xla" else "xla",
-                    qscale=pool_qs,
-                )
-            else:
-                y_dev, bufs = cascade_decimate_stream(
-                    blk, carry.bufs, plan, eng_req, mesh=mesh,
-                    qscale=pool_qs,
-                )
-            t_disp = time.perf_counter() - t0
-            carry.bufs = bufs
 
-            def _flush(y_dev=y_dev, rows=rows, ran=ran, t_disp=t_disp):
-                t1 = time.perf_counter()
-                y = np.asarray(y_dev)
-                t_dev = t_disp + time.perf_counter() - t1
-                lfp.timings["device_s"] += t_dev
-                _count_block(rows, ran, t_dev)
-                carry.consumed += rows
-                s = min(carry.skip_left, y.shape[0])
-                carry.skip_left -= s
-                _emit(lfp, carry, patch, y[s:], rows=rows, ran=ran,
-                      t_dev=t_dev)
-
-            pipe.push(_flush)
+        pipe.push(_flush)
         off += rows
     carry.residual = np.ascontiguousarray(pool[usable:])
     carry.residual_scale = pool_qs

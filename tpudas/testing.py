@@ -158,6 +158,11 @@ def make_synthetic_spool(
     """Write ``n_files`` contiguous files into ``directory`` in the
     given IO format ("dasdae" HDF5 or the native "tdas" stream).
 
+    Files are synthesized on a thread per file (numpy releases the GIL
+    in the bulk math; a deployment-width spool is minutes of work on
+    one core); each file's seed is its index, so the bytes do not
+    depend on the threading.
+
     ``prefix`` names the files ``<prefix>_<i>.<ext>`` — pass a distinct
     prefix when appending a later batch into an existing directory
     (streaming tests), or the new files would overwrite the old.
@@ -169,11 +174,10 @@ def make_synthetic_spool(
     step = np.timedelta64(int(round(1e9 / fs)), "ns")
     n = int(round(file_duration * fs))
     suffix = ".tdas" if format == "tdas" else ".h5"
-    paths = []
-    for i in range(n_files):
-        file_t0 = t0 + i * n * step
+
+    def one(i):
         patch = synthetic_patch(
-            t0=file_t0,
+            t0=t0 + i * n * step,
             duration=file_duration,
             fs=fs,
             n_ch=n_ch,
@@ -183,5 +187,22 @@ def make_synthetic_spool(
         )
         path = os.path.join(directory, f"{prefix}_{i:04d}{suffix}")
         write_patch(patch, path, format=format, **(write_kwargs or {}))
-        paths.append(path)
-    return paths
+        return path
+
+    from concurrent.futures import ThreadPoolExecutor
+
+    workers = max(1, min(n_files, os.cpu_count() or 1))
+    with ThreadPoolExecutor(workers) as pool:
+        return list(pool.map(one, range(n_files)))
+
+
+def max_ulps(got, ref) -> float:
+    """Largest ``|got - ref|`` in f32 ulps of ``ref``'s scale (its
+    largest magnitude): how sharded or batched runs, whose programs
+    trace other shapes, are compared with the single-device run."""
+    got = np.asarray(got, np.float64)
+    ref = np.asarray(ref, np.float64)
+    if got.shape != ref.shape:
+        raise ValueError(f"shapes differ: {got.shape} vs {ref.shape}")
+    eps = float(np.finfo(np.float32).eps)
+    return float(np.abs(got - ref).max()) / (eps * float(np.abs(ref).max()))

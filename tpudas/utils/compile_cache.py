@@ -1,27 +1,37 @@
 """Persistent XLA compilation cache.
 
-The engine's first window pays the jit compile (~20-40 s on a TPU
-backend); in deployments that restart the process per polling round —
-and in the bench's probe/measure/e2e child processes — that cost
-recurs every start.  JAX's persistent compilation cache keys compiled
-executables by (HLO, compile options, backend) and reuses them across
-processes, cutting warm restarts to cache-hit latency.
+The engine's first window pays the jit compile (seconds to tens of
+seconds on a TPU); a deployment that restarts its process per polling
+round pays it at every start.  JAX's persistent compilation cache keys
+compiled executables by (HLO, compile options, backend) and reuses them
+across processes, cutting warm restarts to cache-hit latency.
 
-Opt-in: call :func:`enable_compile_cache` or set the
-``TPUDAS_COMPILE_CACHE`` env var (a directory path, or ``1`` for the
-default location) before the first jit executes.  LFProc and bench.py
-both honour the env var.
+Placement is decided from outside the program:
 
-The reference has no equivalent (scipy executes eagerly); this is the
-TPU rebuild's answer to its zero-warmup property (SURVEY.md §6).
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, and nothing
+  here configures another directory;
+- otherwise the fixed ``<checkout>/.jax_cache`` (git-ignored).  The
+  path is part of JAX's cache key, so it must not move between runs.
+
+The entry points (``LFProc``, the fleet, ``bench.py``,
+``chip_smoke.py``) turn the cache on by default through
+:func:`enable_compile_cache`; ``JAX_ENABLE_COMPILATION_CACHE=false``
+turns it off.
 """
 
 from __future__ import annotations
 
 import os
-import tempfile
 
 from tpudas.obs.registry import get_registry
+
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 
 _ENABLED = False
 _LISTENER_INSTALLED = False
@@ -59,46 +69,30 @@ def _install_metrics_listener() -> None:
         pass
 
 
-def default_cache_dir() -> str:
-    return os.path.join(tempfile.gettempdir(), "tpudas_jax_cache")
+def cache_dir() -> str:
+    """The directory the placement rule selects (module doc)."""
+    return os.environ.get(ENV_DIR) or CHECKOUT_CACHE
 
 
-def enable_compile_cache(path: str | None = None) -> str:
-    """Point JAX's persistent compilation cache at ``path`` (created if
-    missing) and return the directory used.  Idempotent; safe to call
-    before or after backend init, but must precede the first jit
-    compile to benefit it."""
+def enable_compile_cache() -> str:
+    """Turn the persistent cache on at :func:`cache_dir` and return
+    it.  Idempotent; must precede the first jit compile to benefit
+    it."""
     global _ENABLED
     import jax
 
-    if path is None:
-        env = os.environ.get("TPUDAS_COMPILE_CACHE")
-        path = env if env and env != "1" else default_cache_dir()
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    # the cache singleton binds to the directory it first initialized
-    # with; re-pointing the config alone leaves writes going wherever
-    # the singleton was born (even when the CONFIG value round-trips
-    # back unchanged), so reset unconditionally — cheap, and correct
-    # regardless of who touched the config in between
-    try:
+    path = cache_dir()
+    if not _ENABLED and not os.environ.get(ENV_DIR):
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+        # the cache singleton binds to the directory it first
+        # initialized with; re-pointing the config alone leaves writes
+        # going wherever the singleton was born
         from jax._src import compilation_cache as _cc
 
         _cc.reset_cache()
-    except Exception as exc:  # pragma: no cover - private-API drift
-        import warnings
-
-        warnings.warn(
-            "could not reset JAX's compilation-cache singleton "
-            f"({exc!r}); cache writes may target a previously "
-            "configured directory",
-            RuntimeWarning,
-            stacklevel=2,
-        )
     # JAX's default min-compile-time threshold (1 s) already skips the
-    # small host-side jits while caching the window kernels; it is
-    # deliberately NOT overridden here so operator-set thresholds
-    # (JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS) survive
+    # small host-side jits while caching the window kernels
     _install_metrics_listener()
     reg = get_registry()
     reg.gauge(
@@ -115,16 +109,3 @@ def enable_compile_cache(path: str | None = None) -> str:
         pass
     _ENABLED = True
     return path
-
-
-def maybe_enable_from_env() -> str | None:
-    """Enable the cache iff ``TPUDAS_COMPILE_CACHE`` is set (library
-    entry points call this so deployments opt in by environment
-    alone).  Returns the directory when enabled."""
-    if _ENABLED:
-        import jax
-
-        return jax.config.jax_compilation_cache_dir
-    if os.environ.get("TPUDAS_COMPILE_CACHE"):
-        return enable_compile_cache()
-    return None
