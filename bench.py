@@ -495,8 +495,8 @@ def measure_kernel() -> dict:
     # small fraction of the measurement
     iters = int(os.environ.get("BENCH_ITERS", 256 if on_tpu else 16))
     engine = os.environ.get("BENCH_ENGINE", "cascade")
-    # TPU defaults flip the fast path and the shoot-out ON (VERDICT r3
-    # #3: the recorded JSON must carry pallas + engine-compare numbers)
+    # TPU defaults flip the fast path and the shoot-out ON (the
+    # recorded JSON must carry pallas + engine-compare numbers)
     use_pallas = (
         os.environ.get("BENCH_PALLAS", "1" if on_tpu else "0") == "1"
     )
@@ -584,7 +584,7 @@ def measure_kernel() -> dict:
     if report is not None:
         # ground truth of what ran, plus the achieved fraction of the
         # bandwidth roofline (this kernel is HBM-bound by design: ~5
-        # flops/byte; MFU is the wrong lens — VERDICT r3 #4)
+        # flops/byte; MFU is the wrong lens)
         result["stages"] = report["stages"]
         if report["stages_scope"] != "global":
             result["stages_scope"] = report["stages_scope"]

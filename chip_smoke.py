@@ -43,13 +43,9 @@ import time
 
 import numpy as np
 
-FALLBACK_EVENTS = (
-    "pallas_fallback",
-    "pallas_impl_fallback",
-    "stream_pallas_fallback",
-    "window_dp_fallback",
-    "stage_h2d_failed",
-)
+# the one fallback left on these paths: LFProc hands a window to the
+# compute step unstaged when the prefetch thread's device_put fails
+FALLBACK_EVENTS = ("stage_h2d_failed",)
 T0 = "2023-03-22T00:00:00"
 FS = 1000.0
 LF_FREQ = 0.05
@@ -353,9 +349,8 @@ def smoke_one_chip(work, clock, n_ch=2048, n_files=6):
     with open(os.path.join(out_e, ".stream_carry.json")) as fh:
         meta = json.load(fh)
     meta = meta.get("meta", meta)
-    pallas_ok = meta.get("pallas_ok", True)
-    log(f"carry pallas_ok {pallas_ok} (no Pallas latch in the carry)")
-    check(pallas_ok is True, "carry's pallas_ok is true")
+    check("pallas_ok" not in meta,
+          "the carry holds no Pallas latch (pallas_ok)")
 
     # 4. native ingest
     check(load_streamio() is not None and lfp.native_windows == n_win,

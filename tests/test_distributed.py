@@ -81,7 +81,7 @@ class TestRealTwoProcessDCN:
         jax.distributed.initialize (via initialize_multihost env
         config), build one global (2, 4) mesh whose time axis spans the
         process boundary, and run psum + ppermute-halo collectives
-        across it (BASELINE config 5's DCN direction, VERDICT r3 #7)."""
+        across it (BASELINE config 5's DCN direction)."""
         import os
         import socket
         import subprocess

@@ -38,7 +38,7 @@ from tpudas.ops.fir import (
 # the fused-pallas v3 kernel runs exact-f32 VPU arithmetic but groups
 # the per-tap sums by shifted frames, so it is tolerance-pinned (the
 # fused-XLA scan is byte-identical and asserted as such); measured
-# interpret-mode worst case 2.3e-7 relative (PERF.md §11)
+# interpret-mode worst case 2.3e-7 relative (PERF.md §6 (PR 10))
 PALLAS_RTOL = 5e-7
 
 PLANS = [(100.0, 100), (200.0, 40), (50.0, 7)]
@@ -91,7 +91,7 @@ class TestFusedOps:
     def test_fused_pallas_pinned_tolerance(self, fs, ratio):
         """The v3 kernel (interpret mode on CPU = exact f32 dots)
         matches the reference within PALLAS_RTOL, outputs and carry —
-        the recorded tolerance of PERF.md §11."""
+        the recorded tolerance of PERF.md §6 (PR 10)."""
         plan = design_cascade(fs, ratio, 0.45 * fs / ratio, 4)
         blocks = _blocks(plan)
         y0, c0 = _run_stream(plan, blocks, "xla", 5)

@@ -333,7 +333,7 @@ class TestEndToEnd:
         # n_grid=142 with patch=60/buff=10 schedules a final window
         # emitting exactly ONE grid point; the forced cascade engine
         # must derive the ratio from the run-level grid step instead of
-        # raising "grid not sample-aligned" mid-run (ADVICE r1, medium)
+        # raising "grid not sample-aligned" mid-run
         from tpudas.proc.lfproc import schedule_windows
 
         wins = schedule_windows(142, 60, 10)

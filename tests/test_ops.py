@@ -194,7 +194,7 @@ class TestRolling:
         )
 
     def test_std_survives_large_dc_offset(self):
-        # regression (VERDICT r3 weak #4): the raw E[x^2]-E[x]^2
+        # regression: the raw E[x^2]-E[x]^2
         # identity cancels catastrophically in f32 when the data rides
         # a large DC offset — raw counts commonly do
         from tpudas.core.units import s as sec

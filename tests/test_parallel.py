@@ -374,7 +374,7 @@ class TestShardedCascade:
 
 class TestLFProcMesh:
     """The product engine runs mesh-sharded end to end: output files
-    must be byte-identical to the single-device run (VERDICT r3 #2)."""
+    must be byte-identical to the single-device run."""
 
     def _run(self, src, out_dir, mesh, engine="auto"):
         from tpudas import spool

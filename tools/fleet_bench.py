@@ -40,7 +40,7 @@ sequential microbench (``ops_stacked``): N same-plan device steps as
 N solo launches versus ONE stacked launch, the isolated form of the
 launch-overhead claim (the end-to-end fleet on CPU is host-bound —
 spool IO, HDF5 writes, pyramid appends — so the device-step win is
-measured where it lives; PERF.md §13).  The PR 16 artifact:
+measured where it lives; PERF.md §6 (PR 16)).  The PR 16 artifact:
 
     JAX_PLATFORMS=cpu python tools/fleet_bench.py \
         --streams 16,64,256 --batched ab --poll-jitter 0 \
@@ -661,7 +661,7 @@ def main(argv=None) -> int:
         payload["ops_stacked"] = bench_ops_stacked(n_list)
         # the crossover evidence: heavier per-stream work, where the
         # stacked program's compute dominates and batching stops
-        # paying (PERF.md §13 "when batching loses")
+        # paying (PERF.md §6 (PR 16) "when batching loses")
         print("fleet_bench: ops-level crossover (heavy per-stream work)")
         payload["ops_stacked_heavy"] = bench_ops_stacked(
             n_list, n_ch=16, block_sec=4.0

@@ -74,7 +74,7 @@ def copy_kernel_pstream(P, rows, cb):
     per step.  Pure copy (no compute): isolates whether multiple
     streams lift the ~185 GB/s single-stream wall toward the ~510 GB/s
     harness read ceiling — the central hypothesis behind v2's P=4
-    design (PERF.md §4)."""
+    design (PERF.md §5)."""
     nk = T // (rows * P)
     nc = C // cb
     out_rows = rows // 8

@@ -3,7 +3,7 @@
 bench.py's kernel-mode methodology, standalone: NW distinct resident
 windows, the whole timed loop one device dispatch (lax.scan), RNG
 outside the timer, best-of-2.  Single source so every probe measures
-the same way; see PERF.md §2 for why each element is there.
+the same way.
 """
 
 from __future__ import annotations

@@ -337,6 +337,15 @@ def design_cascade(
 # application
 
 
+# the XLA stages' einsum precision on a TPU: "highest" is six bf16 MXU
+# passes, "high" three.  On a v5e (PR 21 chip run,
+# tools/xla_precision_probe.py, 80,000 x 2,048 window) the four-stage
+# chain read 3.02e-7 vs float64 at "highest" and 5.21e-5 at "high" (half
+# the 1e-4 design tolerance), at 10.00 vs 9.89 ms: the stages are
+# HBM-bound, so the extra passes cost ~1 %
+_XLA_STAGE_PRECISION = "highest"
+
+
 def _polyphase_stage_xla(x, hb, R, n_out):
     """One causal decimating stage on (T, C) data:
     ``y[k, c] = sum_j h[j] x[k*R + j, c]`` for k in [0, n_out).
@@ -350,20 +359,20 @@ def _polyphase_stage_xla(x, hb, R, n_out):
     the input B times; this reads it once plus ~B/R of it for ``u`` —
     the streaming stage is memory-bound at production widths, and the
     rewrite measures ~3x faster on stage 0 of the 1 kHz flagship plan
-    at 10k channels on CPU (PERF.md
-    "Sharded streaming").  The b-loop accumulates in the same order as
-    the naive form, and each b-term is the same dot over ``r``, so
-    per-element float arithmetic is unchanged in structure (the stage
-    remains deterministic and layout-independent: channel columns are
-    independent, which is what makes channel sharding bit-exact).
+    at 10k channels on CPU (PERF.md §6, PR 7).  The b-loop accumulates
+    in the same order as the naive form, and each b-term is the same
+    dot over ``r``, so per-element float arithmetic is unchanged in
+    structure (the stage remains deterministic, and channel columns are
+    independent, which is what makes channel sharding bit-exact on the
+    CPU backend; on a v5e 2x2 the sharded stream read 1.50 ulps from
+    single device, PERF.md §7).
 
-    The contraction runs at ``Precision.HIGHEST``: at the default
+    The contraction runs at ``_XLA_STAGE_PRECISION``: at the default
     precision a TPU multiplies f32 operands in one bf16 pass, ~5e-3
     relative error on the chip (caught by LFProc's first-window
     cross-check in PR 21), far outside the cascade's 1e-4 design
     tolerance.  The CPU backend computes f32 either way.
     """
-    import jax
     import jax.numpy as jnp
 
     B = hb.shape[0]
@@ -372,9 +381,7 @@ def _polyphase_stage_xla(x, hb, R, n_out):
     if need > T:
         x = jnp.pad(x, ((0, need - T), (0, 0)))
     xr = x[:need].reshape(n_out + B, R, x.shape[1])
-    u = jnp.einsum(
-        "mrc,br->bmc", xr, hb, precision=jax.lax.Precision.HIGHEST
-    )
+    u = jnp.einsum("mrc,br->bmc", xr, hb, precision=_XLA_STAGE_PRECISION)
     y = jnp.zeros((n_out, x.shape[1]), x.dtype)
     for b in range(B):
         y = y + u[b, b : b + n_out]

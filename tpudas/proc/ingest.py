@@ -43,7 +43,7 @@ Producer-thread observability: each load runs under the
 ``stream.prefetch`` span and aggregate counters/gauges
 (``tpudas_stream_ingest_*``, :func:`tpudas.obs.phases.record_ingest_pipeline`)
 are emitted when the pipeline closes, so the round-phase table can be
-read overlap-aware (PERF.md "Pipelined ingest").
+read overlap-aware (PERF.md §6 (PR 15)).
 """
 
 from __future__ import annotations

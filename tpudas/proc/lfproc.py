@@ -87,7 +87,7 @@ def resolve_gap_tolerance(correct=None, legacy=None):
     return legacy
 
 # first-window cross-check tolerance: the v2 kernel's 3-pass bf16 dot
-# splits land ~1e-5 from the f32 XLA formulation (PERF.md §5) and the
+# splits land ~1e-5 from the f32 XLA formulation (PERF.md §6, PR 21) and the
 # cascade's design tolerance is 1e-4; a Mosaic miscompile produces
 # garbage, not 1e-3-level error, so 1e-3 separates the two cleanly
 _PALLAS_VERIFY_TOL = 1e-3
@@ -472,7 +472,7 @@ class LFProc:
         the device computes the current one, and raw int16 payloads
         dequantize inside the first kernel exactly like this class's
         batch windows do — byte-identical to the synchronous loop
-        (PERF.md "Pipelined ingest"; tests/test_ingest.py pins it)."""
+        (PERF.md §6, PR 15; tests/test_ingest.py pins it)."""
         if self._output_folder is None:
             raise Exception("Please setup output folder first")
         from tpudas.proc.stream import process_increment

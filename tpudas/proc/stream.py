@@ -38,7 +38,7 @@ emission — regenerated identically, filenames are deterministic).  A
 folder with outputs but no carry is a legacy rewind-mode folder; the
 driver falls back to rewind for it.
 
-Ingest is PIPELINED (ISSUE 15, PERF.md "Pipelined ingest"): a bounded
+Ingest is PIPELINED (ISSUE 15, PERF.md §6 (PR 15)): a bounded
 prefetch thread (tpudas.proc.ingest) reads + merges + decodes the
 next slice while the device computes the current one, raw int16
 payloads ship to the device undecoded (dequantization is the first

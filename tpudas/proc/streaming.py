@@ -137,7 +137,7 @@ def run_lowpass_realtime(
     channel-only mesh (no ``time`` axis > 1) keeps the STATEFUL path:
     the stream carry lives as a sharded, donated, device-resident
     pytree between rounds and outputs are byte-identical to the
-    single-device run (PERF.md "Sharded streaming"); a time-sharded
+    single-device run (PERF.md §6 (PR 7)); a time-sharded
     mesh falls back to the window/rewind path, which owns the halo
     exchange — see :attr:`LFProc.mesh`.  Pass a
     :class:`tpudas.utils.profiling.Counters` to
