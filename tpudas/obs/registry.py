@@ -104,7 +104,12 @@ class Counter(_Metric):
             raise ValueError(f"counter increment must be >= 0, got {amount}")
         key = _label_key(self.labelnames, labels)
         with self._lock:
-            self._values[key] = self._values.get(key, 0.0) + float(amount)
+            self._add(key, float(amount))
+
+    def _add(self, key: tuple, amount: float) -> None:
+        # the caller holds self._lock and built the key (the span hot
+        # path updates several metrics under one hold)
+        self._values[key] = self._values.get(key, 0.0) + amount
 
     def value(self, **labels) -> float:
         key = _label_key(self.labelnames, labels)
@@ -150,20 +155,23 @@ class Histogram(_Metric):
 
     def observe(self, value: float, **labels) -> None:
         key = _label_key(self.labelnames, labels)
-        v = float(value)
         with self._lock:
-            state = self._values.get(key)
-            if state is None:
-                # per-bucket (non-cumulative) counts; cumulated at
-                # snapshot time so observe is O(log buckets)
-                state = {"counts": [0] * len(self.buckets), "sum": 0.0,
-                         "count": 0}
-                self._values[key] = state
-            i = _bisect_left(self.buckets, v)
-            if i < len(self.buckets):
-                state["counts"][i] += 1
-            state["sum"] += v
-            state["count"] += 1
+            self._record(key, float(value))
+
+    def _record(self, key: tuple, v: float) -> None:
+        # the caller holds self._lock and built the key
+        state = self._values.get(key)
+        if state is None:
+            # per-bucket (non-cumulative) counts; cumulated at
+            # snapshot time so observe is O(log buckets)
+            state = {"counts": [0] * len(self.buckets), "sum": 0.0,
+                     "count": 0}
+            self._values[key] = state
+        i = _bisect_left(self.buckets, v)
+        if i < len(self.buckets):
+            state["counts"][i] += 1
+        state["sum"] += v
+        state["count"] += 1
 
     def snapshot(self, **labels) -> dict:
         """{"count": n, "sum": s, "buckets": {le: cumulative}} for one
@@ -389,8 +397,15 @@ _SWAP_LOCK = threading.Lock()
 _SCOPE_DEPTH = 0  # active use_registry scopes (overrides kill-switch)
 
 
+# the kill-switch is read on every instrumented call (each span): read
+# os.environ's encoded store, which every os.environ write updates,
+# without the Mapping path's per-call encode and decode (~1 us)
+_OBS_KEY = os.environ.encodekey("TPUDAS_OBS")
+_OBS_OFF = os.environ.encodevalue("0")
+
+
 def obs_enabled() -> bool:
-    return os.environ.get("TPUDAS_OBS", "1") != "0"
+    return os.environ._data.get(_OBS_KEY) != _OBS_OFF
 
 
 def get_registry():

@@ -7,13 +7,17 @@ must never grow memory with uptime), feed the
 ``tpudas_span_seconds{name=...}`` histogram, and export one
 ``log_event("span", ...)`` line each through the existing JSONL
 pipeline (skipped wholesale when no log handler is installed, so the
-default cost is one perf_counter pair, a ring append, and one
-histogram update — a hand-rolled context manager, not
-``@contextmanager``, keeps that under ~10 us on the stream hot path).
+default cost is one perf_counter pair, a ring append, one histogram
+update and the profiler annotation below — a hand-rolled context
+manager, not ``@contextmanager``, keeps that under ~10 us on the
+stream hot path; tests/test_window_spans.py holds it there).
 
-``TPUDAS_TRACE_ANNOTATE=1`` additionally wraps each span in
-``jax.profiler.TraceAnnotation`` so spans line up with
-``device_trace`` / ``TPUDAS_TRACE_DIR`` TensorBoard output.
+Each span is also a ``jax.profiler.TraceAnnotation`` of its name, once
+the process has imported jax: under an active profiler trace it lands
+on the profiler's host plane, on the thread that ran it, on the same
+clock as the device's ops (a span never imports jax itself, so
+host-only processes stay free of it).  Attributes stay on the ring
+and JSONL record only.
 
 ``TPUDAS_OBS=0`` disables recording entirely (same kill-switch as the
 registry); ``TPUDAS_SPAN_RING`` sizes the ring (default 2048 finished
@@ -22,7 +26,9 @@ spans).
 
 from __future__ import annotations
 
+import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -53,7 +59,7 @@ def span_ring_capacity() -> int:
 _lock = threading.Lock()
 _ring: deque = deque(maxlen=span_ring_capacity())
 _local = threading.local()
-_next_id = 0
+_ids = itertools.count(1)
 # finished-span sinks (e.g. the flight recorder's thread-scoped
 # capture, tpudas.obs.flight) — called with each finished span record
 _sinks: list = []
@@ -73,9 +79,8 @@ def remove_span_sink(fn) -> None:
     with _lock:
         if fn in _sinks:
             _sinks.remove(fn)
-# jax.profiler.TraceAnnotation resolved once (None = unresolved,
-# False = unavailable/disabled) — the old device_trace re-imported jax
-# on every call; spans must not repeat that on the hot path
+# jax.profiler.TraceAnnotation, resolved once jax is imported (None =
+# not yet, False = unavailable)
 _annotation_cls = None
 
 
@@ -89,15 +94,14 @@ def _span_stack():
 def _trace_annotation():
     global _annotation_cls
     if _annotation_cls is None:
-        if os.environ.get("TPUDAS_TRACE_ANNOTATE", "0") != "1":
-            _annotation_cls = False
-        else:
-            try:
-                import jax
+        if "jax" not in sys.modules:
+            return None  # not imported here: a span never imports it
+        try:
+            from jax.profiler import TraceAnnotation
 
-                _annotation_cls = jax.profiler.TraceAnnotation
-            except Exception:  # pragma: no cover - backend specific
-                _annotation_cls = False
+            _annotation_cls = TraceAnnotation
+        except Exception:  # pragma: no cover - backend specific
+            _annotation_cls = False
     return _annotation_cls
 
 
@@ -136,7 +140,7 @@ class _Span:
     """Hand-rolled context manager (no ``@contextmanager`` generator
     machinery) for the hot path.  Yields the mutable span record."""
 
-    __slots__ = ("name", "attrs", "rec", "_cm", "_t0", "_reg")
+    __slots__ = ("name", "attrs", "rec", "_cm", "_t0", "_reg", "_stack")
 
     def __init__(self, name, attrs):
         self.name = name
@@ -149,23 +153,22 @@ class _Span:
         reg = _registry_mod.get_registry()
         if reg is _registry_mod._NOOP_REGISTRY:
             return None
-        global _next_id
         stack = _span_stack()
         parent = stack[-1] if stack else None
-        with _lock:
-            _next_id += 1
-            sid = _next_id
         rec = self.rec = {
             "name": str(self.name),
-            "id": sid,
+            "id": next(_ids),
             "parent": None if parent is None else parent["id"],
             "depth": len(stack),
             "attrs": self.attrs,
         }
         stack.append(rec)
+        self._stack = stack
         self._reg = reg
-        ann = _trace_annotation()
-        self._cm = ann(rec["name"]) if ann else None
+        # the name alone: ``#k=v#`` metadata would split the trace's
+        # by-name sums
+        ann = _annotation_cls or _trace_annotation()
+        self._cm = ann(rec["name"]) if ann and ann.is_enabled() else None
         if self._cm is not None:
             self._cm.__enter__()
         rec["start"] = time.time()
@@ -182,17 +185,20 @@ class _Span:
         if exc is not None:
             rec["error"] = repr(exc)[:200]
         rec["duration_s"] = dur
-        _span_stack().pop()
+        self._stack.pop()
         with _lock:
             evicted = len(_ring) == _ring.maxlen
             _ring.append(rec)
         hist, evictions, dropped = _span_metrics(self._reg)
-        if evicted:
-            evictions.inc()
-            # catalogued obs-wide name (ISSUE 13): silent trace loss
-            # must be visible in metrics.prom
-            dropped.inc(reason="ring_full")
-        hist.observe(dur, name=rec["name"])
+        # one hold of the registry's lock, which its metrics share, for
+        # every update: a full ring counts an eviction on each exit.
+        # Silent trace loss must be visible in metrics.prom (the
+        # obs-wide dropped-spans counter)
+        with hist._lock:
+            if evicted:
+                evictions._add((), 1.0)
+                dropped._add(("ring_full",), 1.0)
+            hist._record((rec["name"],), dur)
         for sink in tuple(_sinks):
             try:
                 sink(rec)

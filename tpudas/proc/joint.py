@@ -222,7 +222,6 @@ class JointProc(LFProc):
         if pad_c:
             red = red[:, :-pad_c]
         t_dev = time.perf_counter() - t_dev0
-        self.timings["device_s"] += t_dev
         times = taxis[q : q + m * s : s]
         coords = dict(window_patch.coords)
         coords["time"] = times
@@ -235,9 +234,7 @@ class JointProc(LFProc):
         filename = get_filename(
             result.attrs["time_min"], result.attrs["time_max"]
         )
-        t_w0 = time.perf_counter()
         result.io.write(os.path.join(folder, filename), "dasdae")
-        self.timings["write_s"] += time.perf_counter() - t_w0
         self.rolling_windows += 1
         log_event(
             "rolling_window_emitted",

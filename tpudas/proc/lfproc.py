@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 import os
 import shutil
 import time
@@ -54,6 +55,10 @@ __all__ = ["LFProc", "PallasVerificationError", "check_merge",
 
 
 _GAP_ALIAS_WARNED = False  # the deprecated spelling warns once per process
+# process_time_range calls of this process, in order: the ``job`` attr
+# of every span a call opens (joins a prefetch-thread span to the
+# lfproc.window it fed)
+_JOBS = itertools.count(1)
 
 
 def resolve_gap_tolerance(correct=None, legacy=None):
@@ -297,6 +302,10 @@ class LFProc:
             os.environ.get("TPUDAS_PALLAS_VERIFY", "1") != "0"
         )
         self._run_origin_ns = None  # set per process_time_range run
+        # attrs of the main thread's window-loop spans: the run's job,
+        # and the window in hand once the loop has one
+        self._span_tags = {}
+        self._job_windows = 0  # windows scheduled so far in the job
         self._first_window_of_run = True
         self._dp_proven = set()  # DP keys whose batched kernel passed
 
@@ -501,9 +510,38 @@ class LFProc:
             if delta > 0:
                 reg.counter(metric, help_).inc(delta)
 
+    @contextlib.contextmanager
+    def _step(self, name, key):
+        """One step of the window loop: ``span(name)`` with the
+        window's attrs, its duration added to ``timings[key]`` from the
+        span's own clock reads (a perf_counter pair of its own when
+        spans are off)."""
+        t0 = time.perf_counter()
+        with span(name, **self._span_tags) as rec:
+            yield
+        self.timings[key] += (
+            rec["duration_s"] if rec is not None
+            else time.perf_counter() - t0
+        )
+
+    @staticmethod
+    def _put_window(host, sharding=None, **tags):
+        """H2D of one window's payload: the ``lfproc.h2d`` span from
+        the ``device_put`` call until the array is ready, and the bytes
+        shipped as handed over (raw int16 for quantized windows)."""
+        with span("lfproc.h2d", **tags):
+            arr = jax.device_put(host, sharding)
+            arr.block_until_ready()
+        get_registry().counter(
+            "tpudas_window_h2d_bytes_total",
+            "window payload bytes handed to device_put, as shipped",
+        ).inc(host.nbytes)
+        return arr
+
     # the engine -------------------------------------------------------
-    def _load_window(self, t_lo, t_hi, on_gap):
-        """Host side: read + merge one window from the source spool.
+    def _load_window(self, t_lo, t_hi, on_gap, **tags):
+        """Host side: read + merge one window from the source spool
+        (``tags``: the window's span attrs).
 
         tdas-backed directory spools take the native fast path: per-file
         row segments are planned from the index alone and the C++
@@ -519,18 +557,13 @@ class LFProc:
                 from tpudas.io.tdas import assemble_window_patch
 
                 self.native_windows += 1
-                log_event(
-                    "native_window",
-                    files=len(plan["segments"]),
-                    rows=plan["total_rows"],
-                    payload=plan.get("payload", "float32"),
-                )
                 with span(
                     "lfproc.load_window", native=True,
-                    files=len(plan["segments"]),
+                    files=len(plan["segments"]), rows=plan["total_rows"],
+                    payload=plan.get("payload", "float32"), **tags,
                 ):
                     return assemble_window_patch(plan)
-        with span("lfproc.load_window", native=False):
+        with span("lfproc.load_window", native=False, **tags):
             selected = self._spool.select(time=(t_lo, t_hi))
             # data_gap_tolorance's single meaning (see
             # _default_process_parameters): holes up to that many
@@ -646,11 +679,14 @@ class LFProc:
         else:
             trace_cm = contextlib.nullcontext()
         before = dict(self.timings)
+        self._span_tags = {"job": next(_JOBS)}
+        self._job_windows = 0
         try:
             with trace_cm, span(
                 "lfproc.process_time_range",
                 grid_points=len(time_grid),
                 segments=len(segments),
+                **self._span_tags,
             ):
                 total_windows = self._process_segments(
                     time_grid, segments, on_gap
@@ -661,6 +697,7 @@ class LFProc:
             # window-local origin)
             self._run_origin_ns = None
             self._first_window_of_run = True
+            self._span_tags = {}
             self._mirror_timings(before)
         log_event(
             "process_time_range_done",
@@ -714,41 +751,52 @@ class LFProc:
                 time_grid, windows, on_gap, dt, corner, order
             )
 
-        for i, loaded, emit_times in self._iter_windows(
+        with contextlib.closing(self._iter_windows(
             time_grid, windows, on_gap, self._load_and_stage
-        ):
-            window_patch, staged = loaded
-            if window_patch is None:
-                log_event("window_skipped_gap", index=i + 1)
-                continue
-            self._process_window(
-                window_patch, emit_times, dt, corner, order, staged=staged
-            )
+        )) as loop:
+            for i, loaded, emit_times in loop:
+                window_patch, staged = loaded
+                if window_patch is None:
+                    log_event("window_skipped_gap", index=i + 1)
+                    continue
+                self._process_window(
+                    window_patch, emit_times, dt, corner, order,
+                    staged=staged,
+                )
         return len(windows)
 
     def _iter_windows(self, time_grid, windows, on_gap, loader):
         """Prefetching window iterator shared by the serial and
-        window-DP drivers: ``loader(bg, ed, on_gap)`` runs one window
-        ahead on the worker thread; yields ``(i, loaded, emit_times)``
-        with assemble-wait time accounted."""
+        window-DP drivers: ``loader(bg, ed, on_gap, **tags)`` runs one
+        window ahead on the worker thread; yields ``(i, loaded,
+        emit_times)``.  Each window's main-thread time is one
+        ``lfproc.window`` span, open from before the wait on its load
+        (``lfproc.ingest_wait``, accounted as ``assemble_s``) until the
+        consumer asks for the next window — close the iterator
+        (``contextlib.closing``) so a failing window closes it too."""
+        outer = self._span_tags  # the job's
+        base = self._job_windows
+        self._job_windows += len(windows)
+
+        def submit(k):
+            bg, ed = windows[k][0], windows[k][1]
+            return pool.submit(loader, time_grid[bg], time_grid[ed], on_gap,
+                               **outer, window=base + k)
+
         with ThreadPoolExecutor(max_workers=1) as pool:
-            future = None
-            if windows:
-                w0 = windows[0]
-                future = pool.submit(
-                    loader, time_grid[w0[0]], time_grid[w0[1]], on_gap
-                )
-            for i, (sel_lo, sel_hi, emit_lo, emit_hi) in enumerate(windows):
-                print("Processing patch ", str(i + 1))
-                t_wait = time.perf_counter()
-                loaded = future.result()
-                self.timings["assemble_s"] += time.perf_counter() - t_wait
-                if i + 1 < len(windows):
-                    nxt = windows[i + 1]
-                    future = pool.submit(
-                        loader, time_grid[nxt[0]], time_grid[nxt[1]], on_gap
-                    )
-                yield i, loaded, time_grid[emit_lo:emit_hi]
+            future = submit(0) if windows else None
+            try:
+                for i, (_, _, emit_lo, emit_hi) in enumerate(windows):
+                    print("Processing patch ", str(i + 1))
+                    self._span_tags = {**outer, "window": base + i}
+                    with span("lfproc.window", **self._span_tags):
+                        with self._step("lfproc.ingest_wait", "assemble_s"):
+                            loaded = future.result()
+                        if i + 1 < len(windows):
+                            future = submit(i + 1)
+                        yield i, loaded, time_grid[emit_lo:emit_hi]
+            finally:
+                self._span_tags = outer
 
     def _dp_window_info(self, window_patch, target_times, dt, corner, order):
         """Batchability probe for the window-DP driver: the (plan,
@@ -820,15 +868,13 @@ class LFProc:
             n_ch_local = -(-stack.shape[2] // mesh.shape["ch"])
             stages = stage_engines(plan, n_out, n_ch_local, "auto")
             ran = "cascade-pallas" if "pallas" in stages else "cascade-xla"
-            t0 = time.perf_counter()
-            out = np.asarray(
-                batched_cascade_decimate(
+            with self._step("lfproc.dispatch", "device_s"):
+                out = batched_cascade_decimate(
                     mesh, stack, plan, phase, n_out, engine="auto",
                     batch_axis="time", ch_axis="ch", qscale=qs,
                 )
-            )
-            t_dev = time.perf_counter() - t0
-            self.timings["device_s"] += t_dev
+            with self._step("lfproc.sync", "device_s"):
+                out = np.asarray(out)
             key = infos[0]["key"]
             if (
                 self._pallas_verify
@@ -853,7 +899,7 @@ class LFProc:
                 rel = _pallas_crosscheck(out[0], ref, "window-DP batch")
                 log_event("pallas_crosscheck_dp", rel_err=rel)
                 self._dp_proven.add(key)
-            return out, ran, int(stack.shape[1]), t_dev
+            return out, ran, int(stack.shape[1])
 
         def flush():
             if not pending:
@@ -863,7 +909,7 @@ class LFProc:
                 self._process_window(patch, emit_times, dt, corner, order)
                 pending.clear()
                 return
-            out, ran, rows, t_dev = run_batch()
+            out, ran, rows = run_batch()
             log_event(
                 "window_dp_batch", windows=len(pending), engine=ran,
                 rows=rows, emitted=int(pending[0][2]["n_out"]),
@@ -872,38 +918,39 @@ class LFProc:
                 # joint extras run here too (the per-window hook is
                 # bypassed by batched execution); before the LF write,
                 # same crash-ordering contract as _process_window
-                self._emit_window_extras(
-                    patch, info["host"], info["qs"],
-                    patch.coords["time"], emit_times, dt,
-                    patch.get_sample_step("time"),
-                )
-                self._emit_window_output(
-                    patch, emit_times, dt, out[i], ran,
-                    rows=rows, t_dev=t_dev / len(pending),
-                )
+                with self._step("lfproc.write", "write_s"):
+                    self._emit_window_extras(
+                        patch, info["host"], info["qs"],
+                        patch.coords["time"], emit_times, dt,
+                        patch.get_sample_step("time"),
+                    )
+                    self._emit_window_output(
+                        patch, emit_times, dt, out[i], ran, rows=rows,
+                    )
             pending.clear()
 
-        for i, window_patch, emit_times in self._iter_windows(
+        with contextlib.closing(self._iter_windows(
             time_grid, windows, on_gap, self._load_window
-        ):
-            if window_patch is None:
-                flush()
-                log_event("window_skipped_gap", index=i + 1)
-                continue
-            info = self._dp_window_info(
-                window_patch, emit_times, dt, corner, order
-            )
-            if info is None:
-                flush()
-                self._process_window(
+        )) as loop:
+            for i, window_patch, emit_times in loop:
+                if window_patch is None:
+                    flush()
+                    log_event("window_skipped_gap", index=i + 1)
+                    continue
+                info = self._dp_window_info(
                     window_patch, emit_times, dt, corner, order
                 )
-                continue
-            if pending and info["key"] != pending[0][2]["key"]:
-                flush()
-            pending.append((window_patch, emit_times, info))
-            if len(pending) == nb:
-                flush()
+                if info is None:
+                    flush()
+                    self._process_window(
+                        window_patch, emit_times, dt, corner, order
+                    )
+                    continue
+                if pending and info["key"] != pending[0][2]["key"]:
+                    flush()
+                pending.append((window_patch, emit_times, info))
+                if len(pending) == nb:
+                    flush()
         flush()
         return len(windows)
 
@@ -928,16 +975,18 @@ class LFProc:
     # serial path fits.  TPUDAS_H2D_STAGE=0 disables staging outright.
     _STAGE_MAX_BYTES = 2 << 30
 
-    def _load_and_stage(self, bg, ed, on_gap):
-        """Prefetch-thread body: assemble the window, then START its
-        host->device transfer so H2D overlaps the previous window's
-        device compute and output write (the ingest pipeline is
-        assemble -> stage -> compute -> write; the reference's loop is
-        fully serial, lf_das.py:291-306).  Returns (patch, staged):
-        ``staged`` is the time-major device array (raw int16 for
-        quantized windows) or None when staging does not apply (mesh
-        runs place data with their own shardings)."""
-        window_patch = self._load_window(bg, ed, on_gap)
+    def _load_and_stage(self, bg, ed, on_gap, **tags):
+        """Prefetch-thread body: assemble the window, then transfer it
+        to the device so H2D overlaps the previous window's device
+        compute and output write (the ingest pipeline is assemble ->
+        stage -> compute -> write; the reference's loop is fully
+        serial, lf_das.py:291-306).  This thread waits for the transfer
+        (``lfproc.h2d``), so the main thread waits only in
+        ``lfproc.ingest_wait``.  Returns (patch, staged): ``staged`` is
+        the time-major device array (raw int16 for quantized windows)
+        or None when staging does not apply (mesh runs place data with
+        their own shardings)."""
+        window_patch = self._load_window(bg, ed, on_gap, **tags)
         if (
             window_patch is None
             or self._mesh is not None
@@ -953,7 +1002,7 @@ class LFProc:
         if qscale is None:
             host = np.ascontiguousarray(host, dtype=np.float32)
         try:
-            staged = jax.device_put(host)
+            staged = self._put_window(host, **tags)
         except Exception as exc:  # pragma: no cover - backend-specific
             log_event("stage_h2d_failed", error=str(exc)[:200])
             return window_patch, None
@@ -1122,96 +1171,96 @@ class LFProc:
             )
         else:
             ran = "fft"
-        t_dev0 = time.perf_counter()
         # quantized windows (qs set by _time_major_payload) ship the
         # raw int16 payload and dequantize INSIDE the first device
         # kernel — half the transfer bytes AND half the first stage's
         # HBM read, with no intermediate f32 round trip
         if staged is not None:
-            host32 = staged  # H2D already in flight (prefetch thread)
+            host32 = staged  # already on the device (prefetch thread)
         elif qs is not None:
             host32 = host
         else:
             host32 = host.astype(np.float32, copy=False)
-        if align is not None:
-            def _run_cascade(eng):
-                if time_layout is not None:
-                    from tpudas.parallel.pipeline import (
-                        sharded_cascade_decimate,
+        with self._step("lfproc.dispatch", "device_s"):
+            if align is not None:
+                def _run_cascade(eng):
+                    if time_layout is not None:
+                        from tpudas.parallel.pipeline import (
+                            sharded_cascade_decimate,
+                        )
+
+                        o = sharded_cascade_decimate(
+                            mesh, host32, plan, phase, n_out, engine=eng,
+                            qscale=qs,
+                        )
+                        if o is not None:
+                            return o
+                    return cascade_decimate(
+                        host32, plan, phase, n_out, eng, mesh=mesh, qscale=qs
                     )
 
-                    o = sharded_cascade_decimate(
-                        mesh, host32, plan, phase, n_out, engine=eng,
-                        qscale=qs,
-                    )
-                    if o is not None:
-                        return o
-                return cascade_decimate(
-                    host32, plan, phase, n_out, eng, mesh=mesh, qscale=qs
+                shape_key = (
+                    plan.ratio, plan.delay, int(host.shape[0]), n_out,
+                    int(host.shape[1]), time_layout is not None,
+                    str(host.dtype),  # int16 vs f32 payloads compile apart
                 )
 
-            shape_key = (
-                plan.ratio, plan.delay, int(host.shape[0]), n_out,
-                int(host.shape[1]), time_layout is not None,
-                str(host.dtype),  # int16 vs f32 payloads compile apart
-            )
+                def _run_checked(eng):
+                    o = _run_cascade(eng)
+                    if (
+                        self._pallas_verify
+                        and ran == "cascade-pallas"
+                        and shape_key not in self._pallas_proven
+                    ):
+                        # first window of an unproven shape: cross-check
+                        # the Pallas output against the XLA formulation on
+                        # the SAME window.  A Mosaic miscompile that returns
+                        # silently wrong numbers raises here instead of
+                        # shipping.  Costs one extra XLA run on the first
+                        # window of each shape.
+                        rel = _pallas_crosscheck(
+                            o, np.asarray(_run_cascade("xla")), "first window"
+                        )
+                        log_event(
+                            "pallas_crosscheck", rel_err=rel,
+                            shape=list(host.shape),
+                        )
+                    return o
 
-            def _run_checked(eng):
-                o = _run_cascade(eng)
-                if (
-                    self._pallas_verify
-                    and ran == "cascade-pallas"
-                    and shape_key not in self._pallas_proven
-                ):
-                    # first window of an unproven shape: cross-check
-                    # the Pallas output against the XLA formulation on
-                    # the SAME window.  A Mosaic miscompile that returns
-                    # silently wrong numbers raises here instead of
-                    # shipping.  Costs one extra XLA run on the first
-                    # window of each shape.
-                    rel = _pallas_crosscheck(
-                        o, np.asarray(_run_cascade("xla")), "first window"
+                out = _run_checked("auto")
+                if ran == "cascade-pallas":
+                    self._pallas_proven.add(shape_key)
+            else:
+                idx, w = interp_indices_weights(taxis, target_times)
+                data = host32
+                n_ch = data.shape[1]
+                pad_c = 0
+                if mesh is not None:
+                    from jax.sharding import NamedSharding, PartitionSpec as P
+
+                    # channel sharding only: the FFT runs along the
+                    # replicated time axis, so XLA partitions it over the
+                    # channel batch dimension with zero collectives.
+                    # Channels are zero-padded to the shard multiple (each
+                    # channel is independent, so real columns are
+                    # unaffected) and trimmed below.
+                    pad_c = -n_ch % mesh.shape["ch"]
+                    if pad_c:
+                        pad_fn = (
+                            jnp.pad if isinstance(data, jax.Array) else np.pad
+                        )
+                        data = pad_fn(data, ((0, 0), (0, pad_c)))
+                    data = self._put_window(
+                        data, NamedSharding(mesh, P(None, "ch")),
+                        **self._span_tags,
                     )
-                    log_event(
-                        "pallas_crosscheck", rel_err=rel,
-                        shape=list(host.shape),
-                    )
-                return o
-
-            out = _run_checked("auto")
-            if ran == "cascade-pallas":
-                self._pallas_proven.add(shape_key)
-        else:
-            idx, w = interp_indices_weights(taxis, target_times)
-            data = host32
-            n_ch = data.shape[1]
-            pad_c = 0
-            if mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                # channel sharding only: the FFT runs along the
-                # replicated time axis, so XLA partitions it over the
-                # channel batch dimension with zero collectives.
-                # Channels are zero-padded to the shard multiple (each
-                # channel is independent, so real columns are
-                # unaffected) and trimmed below.
-                pad_c = -n_ch % mesh.shape["ch"]
+                out = lowpass_resample(
+                    data, d_sec, corner, idx, w, order=order, qscale=qs
+                )
                 if pad_c:
-                    pad_fn = (
-                        jnp.pad if isinstance(data, jax.Array) else np.pad
-                    )
-                    data = pad_fn(data, ((0, 0), (0, pad_c)))
-                data = jax.device_put(
-                    data, NamedSharding(mesh, P(None, "ch"))
-                )
-            out = lowpass_resample(
-                data, d_sec, corner, idx, w, order=order, qscale=qs
-            )
-            if pad_c:
-                out = out[:, :n_ch]
-        out = np.asarray(out)  # forces the device chain (host sync)
-        t_dev = time.perf_counter() - t_dev0
-        self.timings["device_s"] += t_dev
+                    out = out[:, :n_ch]
+        with self._step("lfproc.sync", "device_s"):
+            out = np.asarray(out)
         # joint products (tpudas.proc.joint.JointProc): additional
         # outputs computed from the SAME loaded window/payload — one
         # ingest pass, several products.  No-op in the base engine.
@@ -1220,27 +1269,30 @@ class LFProc:
         # window unmarked-as-done (the rolling file is then simply
         # rewritten on resume — filenames are deterministic) rather
         # than leave a permanent hole in the rolling stream.
-        self._emit_window_extras(
-            window_patch, staged if staged is not None else host, qs,
-            taxis, target_times, dt, d_sec,
-        )
-        self._emit_window_output(
-            window_patch, target_times, dt, out, ran,
-            rows=int(host.shape[0]), t_dev=t_dev,
-        )
+        with self._step("lfproc.write", "write_s"):
+            self._emit_window_extras(
+                window_patch, staged if staged is not None else host, qs,
+                taxis, target_times, dt, d_sec,
+            )
+            self._emit_window_output(
+                window_patch, target_times, dt, out, ran,
+                rows=int(host.shape[0]),
+            )
 
     def _emit_window_extras(self, window_patch, payload, qs, taxis,
                             target_times, dt, d_sec):
         """Hook for subclasses emitting extra per-window products.
         ``payload`` is the time-major window — the already-staged
         DEVICE array when the prefetch thread transferred it (no second
-        H2D), the host array otherwise."""
+        H2D), the host array otherwise.  It runs inside the window's
+        ``lfproc.write`` step, so its time counts as ``write_s``."""
 
     def _emit_window_output(self, window_patch, target_times, dt, out, ran,
-                            rows, t_dev=0.0):
+                            rows):
         """Shared tail of window processing: observability, coords,
-        attrs, and the HDF5 write — used by the serial path and by the
-        window-DP driver (which computes ``out`` in a batch)."""
+        attrs, the HDF5 write and the emit listeners — used by the
+        serial path, the window-DP driver (which computes ``out`` in a
+        batch) and the stream; each caller times it as ``write_s``."""
         ax = window_patch.axis_of("time")
         mesh = self._mesh
         # ground truth of what ran, counted after execution
@@ -1270,10 +1322,7 @@ class LFProc:
         filename = get_filename(
             result.attrs["time_min"], result.attrs["time_max"]
         )
-        t_w0 = time.perf_counter()
         result.io.write(os.path.join(self._output_folder, filename), "dasdae")
-        t_write = time.perf_counter() - t_w0
-        self.timings["write_s"] += t_write
         for listener in self._emit_listeners:
             if id(listener) in self._failed_listeners:
                 continue  # raised earlier this round: skip, don't re-fail
@@ -1291,9 +1340,3 @@ class LFProc:
                     "emit_listener_failed",
                     error=f"{type(exc).__name__}: {str(exc)[:200]}",
                 )
-        log_event(
-            "window_timing",
-            device_s=round(t_dev, 5),
-            write_s=round(t_write, 5),
-            engine=ran,
-        )

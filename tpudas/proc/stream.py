@@ -802,7 +802,7 @@ def _open_engine(lfp, carry: StreamCarry, host, t_ns, d_sec,
     return i0
 
 
-def _emit(lfp, carry: StreamCarry, patch, out, rows, ran, t_dev) -> None:
+def _emit(lfp, carry: StreamCarry, patch, out, rows, ran) -> None:
     """Write ``out`` (n, C) at the carry's emission cursor."""
     n = int(out.shape[0])
     if n == 0:
@@ -813,9 +813,9 @@ def _emit(lfp, carry: StreamCarry, patch, out, rows, ran, t_dev) -> None:
     carry.next_emit_ns = int(carry.next_emit_ns + n * carry.step_ns)
     carry.last_emit_ns = int(times[-1].astype(np.int64))
     carry.emitted += n
-    lfp._emit_window_output(
-        patch, times, carry.dt_out, out, ran, rows=rows, t_dev=t_dev
-    )
+    t_w0 = time.perf_counter()
+    lfp._emit_window_output(patch, times, carry.dt_out, out, ran, rows=rows)
+    lfp.timings["write_s"] += time.perf_counter() - t_w0
 
 
 def _pow2_blocks(n_units: int, cap: int) -> list:
@@ -978,8 +978,7 @@ def _consume_cascade(lfp, carry: StreamCarry, patch, new, qs,
             carry.consumed += rows
             s = min(carry.skip_left, y.shape[0])
             carry.skip_left -= s
-            _emit(lfp, carry, patch, y[s:], rows=rows, ran=ran,
-                  t_dev=t_dev)
+            _emit(lfp, carry, patch, y[s:], rows=rows, ran=ran)
 
         pipe.push(_flush)
         off += rows
@@ -1075,7 +1074,7 @@ def _consume_fft(lfp, carry: StreamCarry, patch, new, t_new0_ns, qs,
             carry.skip_left -= s
             _emit(
                 lfp, carry, patch, out[s:].astype(np.float32, copy=False),
-                rows=blk_rows, ran="fft", t_dev=t_dev,
+                rows=blk_rows, ran="fft",
             )
 
         pipe.push(_flush)
