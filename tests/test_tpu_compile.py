@@ -2,7 +2,8 @@
 v5e (on-chip-measurement guide, section 2).  Nothing runs; each test
 lowers and compiles for the chip what the batch and edge paths hand
 Mosaic at deployment widths, so a kernel the chip's compiler refuses
-fails here at no chip time.
+fails here at no chip time.  The FFT kernels are held to radix stages
+and to the chip's HBM by the compiler's own counts.
 
 ``jax.default_backend()`` still says "cpu" here, so the tests call the
 kernels and jitted builders directly, with Pallas interpret mode off.
@@ -162,3 +163,62 @@ def test_mesh_paths(plan, mesh4, monkeypatch, path):
         args = (x, bufs, s)
     text = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# flop per transformed channel-sample above which the compiled FFT is a
+# dense DFT matmul: ~740,000 at 60,750 points, ~6,800 at 65,536 in radix
+# stages (a blocked transform counts its loop body once, so less)
+DENSE_FLOP_PER_SAMPLE = 20_000
+
+
+def _check_fft_program(lowered, rows, n_ch):
+    """The compiled FFT runs in radix stages and fits the chip's HBM."""
+    from tpudas.ops.fftlen import HBM_BYTES
+
+    compiled = lowered.compile()
+    cost = compiled.cost_analysis()
+    if isinstance(cost, list):
+        cost = cost[0]
+    assert cost["flops"] / (rows * n_ch) < DENSE_FLOP_PER_SAMPLE
+    mem = compiled.memory_analysis()
+    held = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes)
+    assert held <= HBM_BYTES
+
+
+@pytest.mark.parametrize("rows", [60001, 59001, 70001, 100001])
+def test_fft_window_kernel(one_chip, rows):
+    """The FFT engine's window kernel, int16 in, 40 outputs, at 10,000
+    channels: the archive job's 60 s windows and its last one, and
+    longer windows that pad to 131,072 points and run in channel
+    blocks."""
+    from tpudas.ops.fftlen import fft_channel_blocks, next_tpu_fft_len
+    from tpudas.proc.lfproc import _lowpass_resample_kernel
+
+    def shape(*s, dtype=F32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    data = shape(rows, 10000, dtype=jnp.int16)
+    nfft = next_tpu_fft_len(rows)
+    lowered = _lowpass_resample_kernel.lower(
+        data, shape(), shape(), shape(40, dtype=jnp.int32), shape(40),
+        nfft=nfft, order=4, scale=shape(),
+        ch_blocks=fft_channel_blocks(nfft, data),
+    )
+    _check_fft_program(lowered, rows, 10000)
+
+
+def test_fft_stream_step(one_chip):
+    """The FFT stream step at 2,048 channels: a 32,768-row int16 block
+    behind a 10 s edge carry on each side at 1 kHz."""
+    from tpudas.ops.filter import _build_fft_stream_fn
+
+    block, carry, n_ch = 32768, 20000, 2048
+    fn = _build_fft_stream_fn(block, carry, n_ch, 1e-3, None, 0.45, 4,
+                              None, "ch", quantized=True)
+    lowered = fn.lower(
+        jax.ShapeDtypeStruct((block, n_ch), jnp.int16, sharding=one_chip),
+        jax.ShapeDtypeStruct((carry, n_ch), F32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), F32, sharding=one_chip),
+    )
+    _check_fft_program(lowered, block + carry, n_ch)

@@ -15,7 +15,8 @@ import pytest
 
 from tpudas import spool
 from tpudas.obs.registry import MetricsRegistry, use_registry
-from tpudas.obs.trace import clear_spans, get_spans, span
+from tpudas.obs.trace import _span_stack, clear_spans, get_spans, span
+from tpudas.proc import lfproc
 from tpudas.proc.lfproc import LFProc
 from tpudas.testing import make_synthetic_spool
 
@@ -51,11 +52,21 @@ def traced_job(tmp_path_factory):
             shipped.append(x.nbytes)
         return put(x, *args, **kwargs)
 
+    # the transform length each window kernel call ran at, beside the
+    # span open around the call
+    kernels = []
+    kernel = lfproc._lowpass_resample_kernel
+
+    def recording_kernel(*args, **kwargs):
+        kernels.append((args[5], _span_stack()[-1]))
+        return kernel(*args, **kwargs)
+
     trace_dir = str(tmp_path_factory.mktemp("trace"))
     reg = MetricsRegistry()
     clear_spans()
     with pytest.MonkeyPatch.context() as mp, use_registry(reg):
         mp.setattr(jax, "device_put", recording_put)
+        mp.setattr(lfproc, "_lowpass_resample_kernel", recording_kernel)
         jax.profiler.start_trace(trace_dir)
         try:
             lfp.process_time_range(np.datetime64("2023-03-22T00:00:00"),
@@ -75,7 +86,7 @@ def traced_job(tmp_path_factory):
                     native.setdefault(ev.name, []).append(
                         (float(ev.start_ns), float(ev.duration_ns)))
     return {"lfp": lfp, "spans": spans, "native": native, "reg": reg,
-            "shipped": shipped}
+            "shipped": shipped, "kernels": kernels}
 
 
 def _named(spans, name):
@@ -147,6 +158,25 @@ def test_prefetch_spans_join_one_window(traced_job):
         for s in _named(spans, name):
             key = (s["attrs"]["job"], s["attrs"]["window"])
             assert keys.count(key) == 1, (name, key)
+
+
+def test_fft_dispatch_carries_its_transform_length(traced_job):
+    """Each FFT window's ``lfproc.dispatch`` says which length ran: the
+    length its window kernel was called with, the power of two its
+    window's rows pad to."""
+    spans = traced_job["spans"]
+    rows = {(s["attrs"]["job"], s["attrs"]["window"]): s["attrs"]["rows"]
+            for s in _named(spans, "lfproc.load_window")}
+    dispatch = _named(spans, "lfproc.dispatch")
+    assert dispatch and len(dispatch) == len(rows)
+    assert len(traced_job["kernels"]) == len(dispatch)
+    for nfft, open_span in traced_job["kernels"]:
+        assert open_span["name"] == "lfproc.dispatch"
+        assert open_span["attrs"]["nfft"] == nfft
+    for s in dispatch:
+        n = rows[(s["attrs"]["job"], s["attrs"]["window"])]
+        nfft = s["attrs"]["nfft"]
+        assert nfft >= n > nfft // 2
 
 
 def test_span_never_imports_jax():

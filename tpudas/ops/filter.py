@@ -25,7 +25,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpudas.ops.fftlen import next_tpu_fft_len
+from tpudas.ops.fftlen import (
+    fft_channel_blocks,
+    map_channel_blocks,
+    next_tpu_fft_len,
+)
 
 from tpudas.core import units as _units
 
@@ -57,21 +61,29 @@ def _butter_mag2(freqs, low, high, order):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("nfft", "order", "has_low", "has_high")
+    jax.jit,
+    static_argnames=("nfft", "order", "has_low", "has_high", "ch_blocks"),
 )
-def _fft_filter_kernel(data, d_sec, low, high, nfft, order, has_low, has_high):
-    """data: (T, C) float32; filter along axis 0. Returns (T, C)."""
+def _fft_filter_kernel(data, d_sec, low, high, nfft, order, has_low, has_high,
+                       ch_blocks=1):
+    """data: (T, C) float32; filter along axis 0. Returns (T, C).
+    ``ch_blocks``: channel blocks the transform runs in, one after
+    another (``fftlen.fft_channel_blocks``)."""
     n = data.shape[0]
-    spec = jnp.fft.rfft(data, n=nfft, axis=0)
-    freqs = jnp.arange(nfft // 2 + 1, dtype=jnp.float32) / (nfft * d_sec)
-    resp = _butter_mag2(
-        freqs,
-        low if has_low else None,
-        high if has_high else None,
-        order,
-    )
-    out = jnp.fft.irfft(spec * resp[:, None], n=nfft, axis=0)
-    return out[:n].astype(data.dtype)
+
+    def band(data):
+        spec = jnp.fft.rfft(data, n=nfft, axis=0)
+        freqs = jnp.arange(nfft // 2 + 1, dtype=jnp.float32) / (nfft * d_sec)
+        resp = _butter_mag2(
+            freqs,
+            low if has_low else None,
+            high if has_high else None,
+            order,
+        )
+        out = jnp.fft.irfft(spec * resp[:, None], n=nfft, axis=0)
+        return out[:n].astype(data.dtype)
+
+    return map_channel_blocks(band, data, ch_blocks)
 
 
 def fft_pass_filter(data, d_sec, low=None, high=None, order=4):
@@ -84,6 +96,9 @@ def fft_pass_filter(data, d_sec, low=None, high=None, order=4):
     if squeeze:
         data = data[:, None]
     nfft = next_tpu_fft_len(int(data.shape[0]))
+    # the program holds the (T, C) f32 output too, and a copy of it
+    # while the blocks' results are joined
+    blocks = fft_channel_blocks(nfft, data, held_bytes=8 * data.size)
     out = _fft_filter_kernel(
         data,
         jnp.float32(d_sec),
@@ -93,6 +108,7 @@ def fft_pass_filter(data, d_sec, low=None, high=None, order=4):
         int(order),
         low is not None,
         high is not None,
+        ch_blocks=blocks,
     )
     return out[:, 0] if squeeze else out
 

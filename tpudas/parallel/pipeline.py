@@ -23,7 +23,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tpudas.ops.fftlen import next_tpu_fft_len
+from tpudas.ops.fftlen import (
+    fft_channel_blocks,
+    map_channel_blocks,
+    next_tpu_fft_len,
+)
 from tpudas.ops.filter import fft_lowpass_response
 from tpudas.parallel.halo import exchange_halo_time
 
@@ -37,11 +41,17 @@ __all__ = [
 def _local_filter_decimate(padded, d_sec, corner, order, halo, t_local, ratio):
     """Filter a halo-padded local block, trim, stride-decimate."""
     nfft = next_tpu_fft_len(int(padded.shape[0]))
-    spec = jnp.fft.rfft(padded, n=nfft, axis=0)
-    resp = fft_lowpass_response(nfft, d_sec, corner, order)
-    filt = jnp.fft.irfft(spec * resp[:, None], n=nfft, axis=0)
-    interior = jax.lax.slice_in_dim(filt, halo, halo + t_local, axis=0)
-    return interior[::ratio].astype(padded.dtype)
+
+    def filter_decimate(padded):
+        spec = jnp.fft.rfft(padded, n=nfft, axis=0)
+        resp = fft_lowpass_response(nfft, d_sec, corner, order)
+        filt = jnp.fft.irfft(spec * resp[:, None], n=nfft, axis=0)
+        interior = jax.lax.slice_in_dim(filt, halo, halo + t_local, axis=0)
+        return interior[::ratio].astype(padded.dtype)
+
+    return map_channel_blocks(
+        filter_decimate, padded, fft_channel_blocks(nfft, padded)
+    )
 
 
 def sharded_lowpass_decimate(

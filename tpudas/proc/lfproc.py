@@ -13,9 +13,11 @@ from the output folder (lf_das.py:214-217). The *execution* differs:
   response multiply → irfft → gather-lerp resample. Datetime math never
   enters jit; gather indices/weights are computed host-side in exact
   float64;
-- FFT length is padded to ``next_fast_len`` and window shapes are
-  constant in steady state, so XLA compiles the kernel at most a few
-  times per run (first/steady/tail).
+- FFT length is padded to the next power of two
+  (``tpudas.ops.fftlen.next_tpu_fft_len``: other lengths can lower to
+  a dense DFT matmul on the TPU) and window shapes are constant in
+  steady state, so XLA compiles the kernel at most a few times per run
+  (first/steady/tail).
 
 The per-window corner frequency is ``0.45 / dt`` — 0.9x the
 post-decimation Nyquist, matching lf_das.py:223.
@@ -35,7 +37,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpudas.ops.fftlen import next_tpu_fft_len
+from tpudas.ops.fftlen import (
+    fft_channel_blocks,
+    map_channel_blocks,
+    next_tpu_fft_len,
+)
 
 from tpudas.core.mapping import FrozenDict
 from tpudas.core.timeutils import (
@@ -197,9 +203,9 @@ def schedule_windows(n_grid: int, patch_size: int, buff_size: int):
     return windows
 
 
-@functools.partial(jax.jit, static_argnames=("nfft", "order"))
+@functools.partial(jax.jit, static_argnames=("nfft", "order", "ch_blocks"))
 def _lowpass_resample_kernel(data, d_sec, corner, idx, w, nfft, order,
-                             scale=None):
+                             scale=None, ch_blocks=1):
     """Fused window kernel: zero-phase low-pass + gather-lerp decimate.
 
     data: (T, C) f32 — or raw int16 with ``scale``, in which case the
@@ -207,21 +213,28 @@ def _lowpass_resample_kernel(data, d_sec, corner, idx, w, nfft, order,
     fuses it into the FFT input read (the quantized tdas ingest path:
     half the H2D bytes, no materialized f32 intermediate).
     idx/w: (K,) gather plan into the filtered rows.
+    ch_blocks: channel blocks the transform runs in, one after another
+    (``fftlen.fft_channel_blocks``), so that a long window fits.
     """
     from tpudas.ops.filter import fft_lowpass_response
 
-    if scale is not None:
-        data = data.astype(jnp.float32) * scale
-    spec = jnp.fft.rfft(data, n=nfft, axis=0)
-    resp = fft_lowpass_response(nfft, d_sec, corner, order)
-    filt = jnp.fft.irfft(spec * resp[:, None], n=nfft, axis=0)
-    lo = jnp.take(filt, idx, axis=0)
-    hi = jnp.take(filt, idx + 1, axis=0)
-    return (lo + (hi - lo) * w[:, None]).astype(jnp.float32)
+    def filter_resample(data):
+        if scale is not None:
+            data = data.astype(jnp.float32) * scale
+        spec = jnp.fft.rfft(data, n=nfft, axis=0)
+        resp = fft_lowpass_response(nfft, d_sec, corner, order)
+        filt = jnp.fft.irfft(spec * resp[:, None], n=nfft, axis=0)
+        lo = jnp.take(filt, idx, axis=0)
+        hi = jnp.take(filt, idx + 1, axis=0)
+        return (lo + (hi - lo) * w[:, None]).astype(jnp.float32)
+
+    return map_channel_blocks(filter_resample, data, ch_blocks)
 
 
-def lowpass_resample(data, d_sec, corner, idx, w, order=4, qscale=None):
-    """Jittable fused pipeline (also the graft-entry/bench step)."""
+def lowpass_resample(data, d_sec, corner, idx, w, order=4, qscale=None,
+                     nfft=None):
+    """Jittable fused pipeline (also the graft-entry/bench step).
+    ``nfft`` defaults to ``next_tpu_fft_len`` of the rows."""
     from tpudas.ops.fir import _check_quantized
 
     if qscale is not None:
@@ -229,7 +242,8 @@ def lowpass_resample(data, d_sec, corner, idx, w, order=4, qscale=None):
         _check_quantized(data, qscale)
     else:
         data = jnp.asarray(data, jnp.float32)
-    nfft = next_tpu_fft_len(int(data.shape[0]))
+    if nfft is None:
+        nfft = next_tpu_fft_len(int(data.shape[0]))
     return _lowpass_resample_kernel(
         data,
         jnp.float32(d_sec),
@@ -239,6 +253,7 @@ def lowpass_resample(data, d_sec, corner, idx, w, order=4, qscale=None):
         nfft,
         int(order),
         scale=None if qscale is None else jnp.float32(qscale),
+        ch_blocks=fft_channel_blocks(nfft, data),
     )
 
 
@@ -511,13 +526,13 @@ class LFProc:
                 reg.counter(metric, help_).inc(delta)
 
     @contextlib.contextmanager
-    def _step(self, name, key):
+    def _step(self, name, key, **attrs):
         """One step of the window loop: ``span(name)`` with the
-        window's attrs, its duration added to ``timings[key]`` from the
-        span's own clock reads (a perf_counter pair of its own when
-        spans are off)."""
+        window's attrs (and ``attrs``), its duration added to
+        ``timings[key]`` from the span's own clock reads (a
+        perf_counter pair of its own when spans are off)."""
         t0 = time.perf_counter()
-        with span(name, **self._span_tags) as rec:
+        with span(name, **self._span_tags, **attrs) as rec:
             yield
         self.timings[key] += (
             rec["duration_s"] if rec is not None
@@ -1181,7 +1196,12 @@ class LFProc:
             host32 = host
         else:
             host32 = host.astype(np.float32, copy=False)
-        with self._step("lfproc.dispatch", "device_s"):
+        # an FFT window's dispatch span says which transform length ran
+        fft_attrs = (
+            {"nfft": next_tpu_fft_len(int(host.shape[0]))}
+            if ran == "fft" else {}
+        )
+        with self._step("lfproc.dispatch", "device_s", **fft_attrs):
             if align is not None:
                 def _run_cascade(eng):
                     if time_layout is not None:
@@ -1255,7 +1275,8 @@ class LFProc:
                         **self._span_tags,
                     )
                 out = lowpass_resample(
-                    data, d_sec, corner, idx, w, order=order, qscale=qs
+                    data, d_sec, corner, idx, w, order=order, qscale=qs,
+                    nfft=fft_attrs["nfft"],
                 )
                 if pad_c:
                     out = out[:, :n_ch]
