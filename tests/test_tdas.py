@@ -515,3 +515,251 @@ class TestWindowPlan:
             )
             is None
         )
+
+
+# Window shapes wide enough that a chunk is the 2,048-row floor (an output
+# row of >= 8 KiB), so a 2,500-row file reads in a full chunk and a tail.
+_CHUNK_ROWS = 2048
+_FILE_ROWS = 2500
+_WIDTH = {"int16": 4096, "float32": 2048}
+_LAYOUTS = {
+    "one_file": [(0, 100, 2400)],
+    "two_files": [(0, 1200, 2500), (1, 0, 2300)],
+    "three_files": [(0, 2000, 2500), (1, 0, 2500), (2, 0, 700)],
+    "one_row_segment": [(0, 0, 2500), (1, 0, 1)],
+}
+
+native_only = pytest.mark.skipif(
+    tdas.load_streamio() is None, reason="no native streamio runtime"
+)
+
+
+@pytest.fixture(scope="module", params=sorted(_WIDTH))
+def wide_spool(request, tmp_path_factory):
+    """Three contiguous 2,500-row tdas files of one payload dtype."""
+    from tpudas.core.patch import Patch
+
+    payload, n_ch = request.param, _WIDTH[request.param]
+    root = tmp_path_factory.mktemp(f"wide_{payload}")
+    rng = np.random.default_rng(7)
+    dt = np.timedelta64(1, "ms")
+    paths = []
+    for i in range(3):
+        data = rng.integers(-30000, 30000, (_FILE_ROWS, n_ch)).astype(
+            np.float32
+        ) * np.float32(1e-3)
+        t0 = np.datetime64("2023-03-22T00:00:00", "ns") + i * _FILE_ROWS * dt
+        patch = Patch(
+            data=data,
+            coords={
+                "time": t0 + np.arange(_FILE_ROWS) * dt,
+                "distance": np.arange(n_ch) * 1.0,
+            },
+            dims=("time", "distance"),
+        )
+        path = str(root / f"w{i}.tdas")
+        tdas.write_tdas(patch, path, dtype=payload, scale=1e-3)
+        paths.append(path)
+    return payload, n_ch, paths
+
+
+def _segments(paths, layout):
+    segs, out_row = [], 0
+    for f, lo, hi in _LAYOUTS[layout]:
+        segs.append((paths[f], lo, hi, out_row))
+        out_row += hi - lo
+    return segs, out_row
+
+
+def _per_file_merge(segs, c_lo, c_hi, monkeypatch):
+    """The window as ``read_tdas`` of each file's rows, merged, on the
+    numpy reader (no native code in the reference)."""
+    with monkeypatch.context() as mp:
+        mp.setattr(tdas, "load_streamio", lambda: None)
+        parts = []
+        for path, lo, hi, _ in segs:
+            hdr = tdas.read_tdas_header(path)
+            t = hdr["t0_ns"] + np.array([lo, hi - 1]) * hdr["dt_ns"]
+            (p,) = tdas.read_tdas(
+                path,
+                time=tuple(np.datetime64(int(v), "ns") for v in t),
+                distance=(float(c_lo), float(c_hi - 1)),
+            )
+            parts.append(p.host_data())
+    return np.concatenate(parts)
+
+
+@native_only
+class TestChunkedAssembly:
+    @pytest.mark.parametrize("n_threads", [1, 2, 8])
+    @pytest.mark.parametrize("span", ["full", "sub"])
+    @pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+    def test_byte_identical_to_per_file_merge(
+        self, wide_spool, layout, span, n_threads, monkeypatch
+    ):
+        payload, n_ch, paths = wide_spool
+        segs, rows = _segments(paths, layout)
+        # the sub-span reads through the bounce buffer
+        c_lo, c_hi = (0, n_ch) if span == "full" else (3, n_ch - 5)
+        want = _per_file_merge(segs, c_lo, c_hi, monkeypatch)
+        got = tdas.assemble_window(
+            segs, c_lo, c_hi, rows, n_threads=n_threads
+        )
+        assert got.dtype == np.float32
+        assert got.tobytes() == want.tobytes()
+        code = 1 if payload == "int16" else 0
+        raw = tdas.assemble_window_raw(
+            segs, c_lo, c_hi, rows, dtype_code=code, n_threads=n_threads
+        )
+        want_raw = np.concatenate([
+            tdas._read_rows_raw_numpy(
+                p, tdas.read_tdas_header(p), lo, hi, c_lo, c_hi
+            )
+            for p, lo, hi, _ in segs
+        ])
+        assert raw.dtype == want_raw.dtype
+        assert raw.tobytes() == want_raw.tobytes()
+        # the window is more than one chunk a segment wherever a
+        # segment passes the chunk floor
+        chunks = tdas.window_chunks(segs, raw.strides[0])
+        assert len(chunks) == len(segs) + sum(
+            (hi - lo - 1) // _CHUNK_ROWS for _, lo, hi, _ in segs
+        )
+
+
+def _within(seconds, fn, *args, **kwargs):
+    """``fn(*args, **kwargs)`` on a thread joined with a timeout: its
+    result, or its exception re-raised; a hang fails the test."""
+    import threading
+
+    box = {}
+
+    def run():
+        try:
+            box["value"] = fn(*args, **kwargs)
+        except Exception as exc:  # re-raised on the test thread below
+            box["error"] = exc
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert not t.is_alive(), f"{fn.__name__} still running after {seconds} s"
+    if "error" in box:
+        raise box["error"]
+    return box["value"]
+
+
+@native_only
+def test_more_workers_than_cores_on_many_segments(tmp_path):
+    """48 files of 64 rows: one chunk a segment, pulled by 32 workers
+    (more than this host's cores) off one counter, with fd reuse across
+    chunks; the window is the per-file merge, in order, every time."""
+    paths = make_synthetic_spool(
+        tmp_path, n_files=48, file_duration=0.64, fs=100.0, n_ch=16,
+        noise=0.05, format="tdas",
+        write_kwargs={"dtype": "int16", "scale": 1e-3},
+    )
+    segs = [(p, 0, 64, 64 * i) for i, p in enumerate(paths)]
+    want = np.concatenate([
+        tdas._read_rows_raw_numpy(p, tdas.read_tdas_header(p), 0, 64, 0, 16)
+        for p in paths
+    ])
+    for _ in range(20):
+        got = _within(60, tdas.assemble_window_raw, segs, 0, 16, 64 * 48,
+                      dtype_code=1, n_threads=32)
+        assert got.tobytes() == want.tobytes()
+
+
+_PLANS = {
+    # the cell's four window layouts over 60 s files at 1 kHz
+    "cell_head": ([(0, 60000), (0, 1)], 20000),
+    "cell_mid": ([(40000, 60000), (0, 40001)], 20000),
+    "cell_late": ([(20000, 60000), (0, 20001)], 20000),
+    "cell_last": ([(1000, 60000)], 20000),
+    "edge_2048_10s": ([(50000, 60000)], 4096),
+    "edge_500_1s": ([(0, 1000)], 1000),
+    "three_files_f32_sub": ([(5, 7000), (0, 7000), (0, 3)], 4 * 1234),
+}
+
+
+@native_only
+class TestWindowChunkPlan:
+    @pytest.mark.parametrize("name", sorted(_PLANS))
+    def test_chunks_tile_the_window(self, name):
+        spans, row_bytes = _PLANS[name]
+        segs, out_row = [], 0
+        for i, (lo, hi) in enumerate(spans):
+            segs.append((f"f{i}.tdas", lo, hi, out_row))
+            out_row += hi - lo
+        chunks = tdas.window_chunks(segs, row_bytes)
+        covered = np.zeros(out_row, np.int64)
+        for seg, lo, hi, o0 in chunks:
+            _, s_lo, s_hi, s_o0 = segs[seg]
+            assert s_lo <= lo < hi <= s_hi  # inside its segment
+            assert o0 == s_o0 + (lo - s_lo)
+            covered[o0 : o0 + hi - lo] += 1
+            if hi != s_hi:  # only a segment's tail may be short
+                assert hi - lo >= _CHUNK_ROWS
+        assert (covered == 1).all()
+
+    def test_chunk_count_follows_the_bytes(self):
+        big = tdas.window_chunks(
+            [("a", 0, 60000, 0), ("b", 0, 1, 60000)], 10000 * 2
+        )
+        assert len(big) > 8
+        assert len(tdas.window_chunks([("a", 0, 1000, 0)], 500 * 2)) == 1
+        # at the same rows, wider rows never mean fewer chunks
+        counts = [
+            len(tdas.window_chunks([("a", 0, 60000, 0)], w))
+            for w in (1000, 8192, 40000, 400000)
+        ]
+        assert counts == sorted(counts) and counts[0] < counts[-1]
+
+    @pytest.mark.parametrize("n_threads", [1, 3, 8, 64])
+    def test_threads_bounded_by_chunks(self, wide_spool, n_threads):
+        from tpudas.obs.trace import span
+
+        payload, n_ch, paths = wide_spool
+        segs, rows = _segments(paths, "three_files")
+        with span("test.read") as rec:
+            tdas.assemble_window(segs, 0, n_ch, rows, n_threads=n_threads)
+        if rec is None:
+            pytest.skip("spans are off")
+        chunks = len(tdas.window_chunks(segs, n_ch * 4))
+        assert rec["attrs"]["chunks"] == chunks
+        assert 1 <= rec["attrs"]["threads"] <= min(n_threads, chunks)
+
+
+@native_only
+class TestChunkedAssemblyErrors:
+    @pytest.mark.parametrize("n_threads", [1, 8])
+    @pytest.mark.parametrize("fault", ["truncated", "removed"])
+    @pytest.mark.parametrize("payload", ["int16", "float32"])
+    def test_second_file_fault_raises(
+        self, tmp_path, payload, fault, n_threads
+    ):
+        n_ch = _WIDTH[payload]
+        paths = make_synthetic_spool(
+            tmp_path, n_files=3, file_duration=2.5, fs=1000.0, n_ch=n_ch,
+            noise=0.05, format="tdas",
+            write_kwargs={"dtype": payload, "scale": 1e-3},
+        )
+        segs, rows = _segments(paths, "three_files")
+        bad = paths[1]
+        if fault == "removed":
+            os.remove(bad)
+        else:  # the header stays valid; the payload ends mid-chunk
+            es = 2 if payload == "int16" else 4
+            os.truncate(bad, 64 + 3000 // 2 * n_ch * es)
+        hdr = tdas.read_tdas_header(paths[0])
+        plan = {
+            "segments": segs, "c_lo": 0, "c_hi": n_ch, "total_rows": rows,
+            "t0_ns": hdr["t0_ns"] + 2000 * hdr["dt_ns"],
+            "dt_ns": hdr["dt_ns"], "d0": hdr["d0"], "dx": hdr["dx"],
+            "payload": payload, "scale": 1e-3 if payload == "int16" else None,
+        }
+        with pytest.raises(OSError):
+            _within(60, tdas.assemble_window_patch, plan, n_threads=n_threads)
+        with pytest.raises(OSError):
+            _within(60, tdas.assemble_window, segs, 0, n_ch, rows,
+                    n_threads=n_threads)

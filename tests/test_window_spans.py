@@ -149,6 +149,19 @@ def test_h2d_bytes_counter_counts_what_was_shipped(traced_job):
     assert got == sum(shipped)
 
 
+def test_window_read_counts_its_chunks(traced_job):
+    """Each native window read puts its chunk and worker counts on its
+    ``lfproc.load_window`` span, and the registry counts the chunks."""
+    loads = _named(traced_job["spans"], "lfproc.load_window")
+    assert loads and all(s["attrs"]["native"] for s in loads)
+    for s in loads:
+        a = s["attrs"]
+        assert a["chunks"] >= a["files"] >= 1
+        assert 1 <= a["threads"] <= a["chunks"]
+    got = traced_job["reg"].value("tpudas_window_read_chunks_total")
+    assert got == sum(s["attrs"]["chunks"] for s in loads)
+
+
 def test_prefetch_spans_join_one_window(traced_job):
     spans = traced_job["spans"]
     keys = [(s["attrs"]["job"], s["attrs"]["window"])

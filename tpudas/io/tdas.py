@@ -25,6 +25,8 @@ import numpy as np
 from tpudas.core.patch import Patch
 from tpudas.core.timeutils import to_datetime64
 from tpudas.native import load_streamio
+from tpudas.obs.registry import get_registry
+from tpudas.obs.trace import annotate
 
 FORMAT_NAME = "tdas"
 _MAGIC = b"TDAS"
@@ -444,7 +446,7 @@ def assemble_window_patch(plan, n_threads=None) -> Patch:
 
 
 def _segment_arrays(segments):
-    """ctypes marshaling shared by both native assemblers."""
+    """ctypes marshaling shared by the native assemblers and planner."""
     n = len(segments)
     return (
         (ctypes.c_char_p * n)(*[os.fsencode(s[0]) for s in segments]),
@@ -453,6 +455,50 @@ def _segment_arrays(segments):
         (ctypes.c_uint64 * n)(*[int(s[3]) for s in segments]),
         n,
     )
+
+
+def window_chunks(segments, row_bytes):
+    """The row chunks the native assemblers cut ``segments`` into, for
+    output rows of ``row_bytes``: ``(segment, row_lo, row_hi,
+    out_row0)`` each, in the order the workers pull them; None without
+    the native runtime."""
+    lib = load_streamio()
+    if lib is None:
+        return None
+    _, row_lo, row_hi, out_r0, n = _segment_arrays(segments)
+    count = lib.tdas_plan_window_chunks(
+        row_lo, row_hi, out_r0, n, int(row_bytes), None, 0
+    )
+    flat = (ctypes.c_uint64 * (4 * count))()
+    lib.tdas_plan_window_chunks(
+        row_lo, row_hi, out_r0, n, int(row_bytes), flat, count
+    )
+    return [tuple(flat[4 * k : 4 * k + 4]) for k in range(count)]
+
+
+def _record_read(chunks, threads):
+    """A window read's chunk and worker counts, on the span open around
+    it (``lfproc.load_window``) and in the registry."""
+    annotate(chunks=chunks, threads=threads)
+    get_registry().counter(
+        "tpudas_window_read_chunks_total",
+        "row chunks read by the window assemblers",
+    ).inc(chunks)
+
+
+def _assemble_native(lib, fn, segments, *args, n_threads):
+    """Call native assembler ``fn`` (``args``: its arguments between the
+    segments and the thread count), record its counts, raise on error."""
+    paths, row_lo, row_hi, out_r0, n = _segment_arrays(segments)
+    chunks, threads = ctypes.c_int(0), ctypes.c_int(0)
+    rc = getattr(lib, fn)(
+        paths, row_lo, row_hi, out_r0, n, *args,
+        int(n_threads or _default_threads()),
+        ctypes.byref(chunks), ctypes.byref(threads),
+    )
+    if rc != 0:
+        raise OSError(rc, f"{fn} failed")
+    _record_read(chunks.value, threads.value)
 
 
 def assemble_window_raw(
@@ -476,22 +522,13 @@ def assemble_window_raw(
             out[o0 : o0 + (r_hi - r_lo)] = _read_rows_raw_numpy(
                 path, hdr, r_lo, r_hi, c_lo, c_hi
             )
+        _record_read(len(segments), 1)
         return out
-    paths, row_lo, row_hi, out_r0, n = _segment_arrays(segments)
-    rc = lib.tdas_assemble_window_raw(
-        paths,
-        row_lo,
-        row_hi,
-        out_r0,
-        n,
-        int(c_lo),
-        int(c_hi),
-        int(dtype_code),
-        out.ctypes.data_as(ctypes.c_void_p),
-        int(n_threads or _default_threads()),
+    _assemble_native(
+        lib, "tdas_assemble_window_raw", segments, int(c_lo), int(c_hi),
+        int(dtype_code), out.ctypes.data_as(ctypes.c_void_p),
+        n_threads=n_threads,
     )
-    if rc != 0:
-        raise OSError(rc, "tdas_assemble_window_raw failed")
     return out
 
 
@@ -507,19 +544,11 @@ def assemble_window(segments, c_lo, c_hi, total_rows, n_threads=None):
             out[o0 : o0 + (r_hi - r_lo)] = _read_block_numpy(
                 path, hdr, r_lo, r_hi, c_lo, c_hi
             )
+        _record_read(len(segments), 1)
         return out
-    paths, row_lo, row_hi, out_r0, n = _segment_arrays(segments)
-    rc = lib.tdas_assemble_window(
-        paths,
-        row_lo,
-        row_hi,
-        out_r0,
-        n,
-        int(c_lo),
-        int(c_hi),
+    _assemble_native(
+        lib, "tdas_assemble_window", segments, int(c_lo), int(c_hi),
         out.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-        int(n_threads or _default_threads()),
+        n_threads=n_threads,
     )
-    if rc != 0:
-        raise OSError(rc, "tdas_assemble_window failed")
     return out

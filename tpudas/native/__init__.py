@@ -101,15 +101,20 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.tdas_read_block.argtypes = [
         ctypes.c_char_p, u64, u64, u32, u32, p(f32), ctypes.c_int,
     ]
-    lib.tdas_assemble_window.restype = ctypes.c_int
+    i32 = ctypes.c_int
+    lib.tdas_assemble_window.restype = i32
     lib.tdas_assemble_window.argtypes = [
-        p(ctypes.c_char_p), p(u64), p(u64), p(u64), ctypes.c_int, u32, u32,
-        p(f32), ctypes.c_int,
+        p(ctypes.c_char_p), p(u64), p(u64), p(u64), i32, u32, u32,
+        p(f32), i32, p(i32), p(i32),
     ]
-    lib.tdas_assemble_window_raw.restype = ctypes.c_int
+    lib.tdas_assemble_window_raw.restype = i32
     lib.tdas_assemble_window_raw.argtypes = [
-        p(ctypes.c_char_p), p(u64), p(u64), p(u64), ctypes.c_int, u32, u32,
-        u32, ctypes.c_void_p, ctypes.c_int,
+        p(ctypes.c_char_p), p(u64), p(u64), p(u64), i32, u32, u32,
+        u32, ctypes.c_void_p, i32, p(i32), p(i32),
+    ]
+    lib.tdas_plan_window_chunks.restype = i32
+    lib.tdas_plan_window_chunks.argtypes = [
+        p(u64), p(u64), p(u64), i32, u64, p(u64), i32,
     ]
     return lib
 

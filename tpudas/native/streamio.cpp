@@ -27,6 +27,7 @@
 //
 // All functions return 0 on success or a positive errno-style code.
 
+#include <algorithm>
 #include <atomic>
 #include <cerrno>
 #include <cstdint>
@@ -45,6 +46,13 @@ namespace {
 constexpr uint32_t kMagic = 0x53414454;  // "TDAS" little-endian
 constexpr uint32_t kVersion = 1;
 constexpr size_t kHeaderSize = 64;
+// a window chunk aims at this many output bytes, and takes at least
+// kMinChunkRows rows; channel sub-spans are read through a bounce
+// buffer of kBounceBytes
+constexpr size_t kChunkBytes = size_t{16} << 20;
+constexpr uint64_t kMinChunkRows = 2048;
+constexpr size_t kBounceBytes = size_t{8} << 20;
+constexpr size_t kPageBytes = 4096;  // the smallest page: touches hit every page
 
 #pragma pack(push, 1)
 struct TdasHeader {
@@ -89,74 +97,159 @@ int pread_full(int fd, void* dst, size_t bytes, off_t off) {
 }
 
 // Read rows [t_lo, t_hi) x channels [c_lo, c_hi) of one open file into
-// out (row-major (t_hi-t_lo, c_hi-c_lo) f32), converting i16 if
-// needed. IO is done in multi-MB contiguous preads (one syscall per
-// ~8 MB, not per row); channel sub-spans are extracted from the
-// chunk buffer in memory.
+// out (row-major (t_hi-t_lo, c_hi-c_lo)). With `decode`, out is f32 and
+// i16 payload is converted (* scale); without it, out keeps the
+// payload dtype and only the channel slice is copied (the device-decode
+// ingest path, where quantized int16 samples cross PCIe at half the
+// float32 byte count and the TPU does the (cast * scale) decode).
+// Full-width rows that need no conversion are one pread straight into
+// out; otherwise IO is done in ~8 MB contiguous preads into `bounce`
+// (one syscall per ~8 MB, not per row) and the channel sub-span is
+// extracted in memory.
 int read_rows(int fd, const TdasHeader& h, uint64_t t_lo, uint64_t t_hi,
-              uint32_t c_lo, uint32_t c_hi, float* out) {
+              uint32_t c_lo, uint32_t c_hi, bool decode, unsigned char* out,
+              std::vector<unsigned char>& bounce) {
   const size_t es = dtype_size(h.dtype);
   const size_t row_bytes = static_cast<size_t>(h.n_ch) * es;
   const size_t span_ch = c_hi - c_lo;
-
-  // fast path: full rows, already float32 — one contiguous read
-  if (c_lo == 0 && c_hi == h.n_ch && h.dtype == 0) {
-    return pread_full(fd, out, (t_hi - t_lo) * row_bytes,
+  const bool convert = decode && h.dtype == 1;
+  if (c_lo == 0 && c_hi == h.n_ch && !convert) {
+    const size_t bytes = (t_hi - t_lo) * row_bytes;
+    // Fault the destination in from this thread before the read. A
+    // pread into untouched pages faults them inside the kernel's copy,
+    // which a sandboxed kernel such as gVisor serialises across
+    // threads: on a gVisor TPU v5e host a 1.2 GB window read into fresh
+    // pages took ~1 s on 1 to 12 threads, and 0.07 s into touched ones.
+    volatile unsigned char* dst = out;
+    for (size_t i = 0; i < bytes; i += kPageBytes) dst[i] = 0;
+    if (bytes > 0) dst[bytes - 1] = 0;
+    return pread_full(fd, out, bytes,
                       static_cast<off_t>(kHeaderSize + t_lo * row_bytes));
   }
-
-  const size_t rows_per_chunk =
-      std::max<size_t>(1, (size_t{8} << 20) / row_bytes);
-  std::vector<unsigned char> buf(rows_per_chunk * row_bytes);
-  for (uint64_t t = t_lo; t < t_hi; t += rows_per_chunk) {
-    const uint64_t n = std::min<uint64_t>(rows_per_chunk, t_hi - t);
-    int rc = pread_full(fd, buf.data(), n * row_bytes,
+  const size_t out_row_bytes = span_ch * (decode ? sizeof(float) : es);
+  const size_t rows_per_read =
+      std::max<size_t>(1, kBounceBytes / row_bytes);
+  bounce.resize(rows_per_read * row_bytes);
+  for (uint64_t t = t_lo; t < t_hi; t += rows_per_read) {
+    const uint64_t n = std::min<uint64_t>(rows_per_read, t_hi - t);
+    int rc = pread_full(fd, bounce.data(), n * row_bytes,
                         static_cast<off_t>(kHeaderSize + t * row_bytes));
     if (rc != 0) return rc;
     for (uint64_t r = 0; r < n; ++r) {
       const unsigned char* src =
-          buf.data() + r * row_bytes + static_cast<size_t>(c_lo) * es;
-      float* orow = out + (t - t_lo + r) * span_ch;
-      if (h.dtype == 1) {
+          bounce.data() + r * row_bytes + static_cast<size_t>(c_lo) * es;
+      unsigned char* orow = out + (t - t_lo + r) * out_row_bytes;
+      if (convert) {
         const int16_t* raw = reinterpret_cast<const int16_t*>(src);
+        float* o = reinterpret_cast<float*>(orow);
         for (size_t c = 0; c < span_ch; ++c)
-          orow[c] = static_cast<float>(raw[c]) * h.scale;
+          o[c] = static_cast<float>(raw[c]) * h.scale;
       } else {
-        std::memcpy(orow, src, span_ch * es);
+        std::memcpy(orow, src, out_row_bytes);
       }
     }
   }
   return 0;
 }
 
-// Raw variant of read_rows: channel-slice memcpy only, NO numeric
-// conversion — feeds the device-decode ingest path, where quantized
-// int16 samples cross PCIe at half the float32 byte count and the TPU
-// does the (cast * scale) decode.
-int read_rows_raw(int fd, const TdasHeader& h, uint64_t t_lo, uint64_t t_hi,
-                  uint32_t c_lo, uint32_t c_hi, unsigned char* out) {
-  const size_t es = dtype_size(h.dtype);
-  const size_t row_bytes = static_cast<size_t>(h.n_ch) * es;
-  const size_t span_ch = c_hi - c_lo;
-  if (c_lo == 0 && c_hi == h.n_ch) {
-    return pread_full(fd, out, (t_hi - t_lo) * row_bytes,
-                      static_cast<off_t>(kHeaderSize + t_lo * row_bytes));
-  }
-  const size_t rows_per_chunk =
-      std::max<size_t>(1, (size_t{8} << 20) / row_bytes);
-  std::vector<unsigned char> buf(rows_per_chunk * row_bytes);
-  for (uint64_t t = t_lo; t < t_hi; t += rows_per_chunk) {
-    const uint64_t n = std::min<uint64_t>(rows_per_chunk, t_hi - t);
-    int rc = pread_full(fd, buf.data(), n * row_bytes,
-                        static_cast<off_t>(kHeaderSize + t * row_bytes));
-    if (rc != 0) return rc;
-    for (uint64_t r = 0; r < n; ++r) {
-      std::memcpy(out + (t - t_lo + r) * span_ch * es,
-                  buf.data() + r * row_bytes + static_cast<size_t>(c_lo) * es,
-                  span_ch * es);
+// One row chunk of a window: rows [lo, hi) of file `file`, landing at
+// output row `out_row`.
+struct Chunk {
+  int file;
+  uint64_t lo, hi, out_row;
+};
+
+// Cut each file segment [row_lo[i], row_hi[i]) into chunks of
+// max(kMinChunkRows, kChunkBytes / out_row_bytes) rows, so the count
+// follows the window's bytes: a chunk never crosses a segment, and
+// only a segment's last chunk may be shorter.
+std::vector<Chunk> plan_chunks(const uint64_t* row_lo, const uint64_t* row_hi,
+                               const uint64_t* out_row0, int n_files,
+                               size_t out_row_bytes) {
+  const uint64_t rows = std::max<uint64_t>(
+      kMinChunkRows, kChunkBytes / std::max<size_t>(1, out_row_bytes));
+  std::vector<Chunk> chunks;
+  for (int i = 0; i < n_files; ++i) {
+    for (uint64_t lo = row_lo[i]; lo < row_hi[i]; lo += rows) {
+      chunks.push_back({i, lo, std::min(row_hi[i], lo + rows),
+                        out_row0[i] + (lo - row_lo[i])});
     }
   }
-  return 0;
+  return chunks;
+}
+
+// Multi-file window assembly: for file i, copy rows [row_lo[i],
+// row_hi[i]) x ch [c_lo, c_hi) into out starting at output row
+// out_row0[i]. The segments are cut into row chunks (plan_chunks) that
+// min(n_threads, chunks) workers pull from one atomic counter, each
+// reading straight into its chunk's output rows, so the reads and the
+// first-touch page faults of a large window are spread over every
+// worker, not one thread per file. Each worker opens its own fd per
+// file it reads and checks the header: with `decode` any payload
+// dtype (out is f32), without it `raw_dtype` only (out keeps it). The
+// first nonzero code from any chunk is returned, after every worker
+// has joined. chunks_out / threads_out, when given, receive the chunk
+// and worker counts.
+int assemble(const char* const* paths, const uint64_t* row_lo,
+             const uint64_t* row_hi, const uint64_t* out_row0, int n_files,
+             uint32_t c_lo, uint32_t c_hi, bool decode, uint32_t raw_dtype,
+             unsigned char* out, int n_threads, int* chunks_out,
+             int* threads_out) {
+  if (n_files < 0 || (!decode && raw_dtype != 0 && raw_dtype != 1))
+    return EINVAL;
+  if (c_lo > c_hi) return ERANGE;
+  for (int i = 0; i < n_files; ++i)
+    if (row_lo[i] > row_hi[i]) return ERANGE;
+  const size_t out_row_bytes = static_cast<size_t>(c_hi - c_lo) *
+                               (decode ? sizeof(float) : dtype_size(raw_dtype));
+  const std::vector<Chunk> chunks =
+      plan_chunks(row_lo, row_hi, out_row0, n_files, out_row_bytes);
+  const int n_workers = static_cast<int>(std::min<size_t>(
+      static_cast<size_t>(std::max(n_threads, 1)), chunks.size()));
+  if (chunks_out) *chunks_out = static_cast<int>(chunks.size());
+  if (threads_out) *threads_out = n_workers;
+
+  std::atomic<size_t> next{0};
+  std::atomic<int> err{0};
+  auto worker = [&]() {
+    int fd = -1;
+    int file = -1;
+    TdasHeader h;
+    std::vector<unsigned char> bounce;
+    for (;;) {
+      const size_t k = next.fetch_add(1);
+      if (k >= chunks.size() || err.load() != 0) break;
+      const Chunk& c = chunks[k];
+      int rc = 0;
+      if (c.file != file) {
+        if (fd >= 0) close(fd);
+        file = c.file;
+        fd = open(paths[file], O_RDONLY);
+        if (fd < 0) {
+          rc = errno ? errno : EIO;
+        } else {
+          rc = read_header_fd(fd, &h);
+          if (rc == 0 && !decode && h.dtype != raw_dtype) rc = EINVAL;
+          if (rc == 0 && (row_hi[file] > h.n_time || c_hi > h.n_ch))
+            rc = ERANGE;
+        }
+      }
+      if (rc == 0)
+        rc = read_rows(fd, h, c.lo, c.hi, c_lo, c_hi, decode,
+                       out + c.out_row * out_row_bytes, bounce);
+      if (rc != 0) {
+        int none = 0;
+        err.compare_exchange_strong(none, rc);
+        break;
+      }
+    }
+    if (fd >= 0) close(fd);
+  };
+  std::vector<std::thread> workers;
+  for (int i = 1; i < n_workers; ++i) workers.emplace_back(worker);
+  if (n_workers > 0) worker();
+  for (auto& w : workers) w.join();
+  return err.load();
 }
 
 }  // namespace
@@ -209,124 +302,62 @@ int tdas_read_header(const char* path, uint64_t* t0_ns, uint64_t* dt_ns,
 }
 
 // Threaded single-file block read: rows [t_lo, t_hi) x ch [c_lo, c_hi)
-// into out (f32 row-major).
+// into out (f32 row-major): a window of one segment.
 int tdas_read_block(const char* path, uint64_t t_lo, uint64_t t_hi,
                     uint32_t c_lo, uint32_t c_hi, float* out,
                     int n_threads) {
-  int fd = open(path, O_RDONLY);
-  if (fd < 0) return errno ? errno : EIO;
-  TdasHeader h;
-  int rc = read_header_fd(fd, &h);
-  if (rc != 0) {
-    close(fd);
-    return rc;
-  }
-  if (t_hi > h.n_time || c_hi > h.n_ch || t_lo > t_hi || c_lo > c_hi) {
-    close(fd);
-    return ERANGE;
-  }
-  const uint64_t rows = t_hi - t_lo;
-  const size_t span_ch = c_hi - c_lo;
-  if (n_threads < 1) n_threads = 1;
-  const uint64_t min_rows_per_thread = 2048;
-  uint64_t want =
-      rows / min_rows_per_thread ? rows / min_rows_per_thread : 1;
-  if (static_cast<uint64_t>(n_threads) > want)
-    n_threads = static_cast<int>(want);
-
-  std::atomic<int> err{0};
-  std::vector<std::thread> workers;
-  const uint64_t chunk = (rows + n_threads - 1) / n_threads;
-  for (int i = 0; i < n_threads; ++i) {
-    const uint64_t lo = t_lo + static_cast<uint64_t>(i) * chunk;
-    if (lo >= t_hi) break;
-    const uint64_t hi = std::min(t_hi, lo + chunk);
-    workers.emplace_back([&, lo, hi]() {
-      int r = read_rows(fd, h, lo, hi, c_lo, c_hi,
-                        out + (lo - t_lo) * span_ch);
-      if (r != 0) err.store(r);
-    });
-  }
-  for (auto& w : workers) w.join();
-  close(fd);
-  return err.load();
+  const uint64_t out_row0 = 0;
+  return assemble(&path, &t_lo, &t_hi, &out_row0, 1, c_lo, c_hi, true, 0,
+                  reinterpret_cast<unsigned char*>(out), n_threads, nullptr,
+                  nullptr);
 }
 
-// Parallel multi-file window assembly: for file i, copy rows
-// [row_lo[i], row_hi[i]) x ch [c_lo, c_hi) into out starting at output
-// row out_row0[i]. Files are processed by a pool of n_threads workers
-// pulling from an atomic queue — this is the host half of the
-// overlap-save window pipeline.
+// Parallel multi-file window assembly into a float32 buffer, decoding
+// int16 payload: the host half of the overlap-save window pipeline
+// (see assemble for the segments, chunks and counts).
 int tdas_assemble_window(const char** paths, const uint64_t* row_lo,
                          const uint64_t* row_hi, const uint64_t* out_row0,
                          int n_files, uint32_t c_lo, uint32_t c_hi,
-                         float* out, int n_threads) {
-  if (n_files < 0) return EINVAL;
-  std::atomic<int> next{0};
-  std::atomic<int> err{0};
-  const size_t span_ch = c_hi - c_lo;
-  auto worker = [&]() {
-    for (;;) {
-      int i = next.fetch_add(1);
-      if (i >= n_files || err.load() != 0) return;
-      int rc = tdas_read_block(paths[i], row_lo[i], row_hi[i], c_lo, c_hi,
-                               out + out_row0[i] * span_ch, 1);
-      if (rc != 0) err.store(rc);
-    }
-  };
-  if (n_threads < 1) n_threads = 1;
-  if (n_threads > n_files) n_threads = n_files;
-  std::vector<std::thread> workers;
-  for (int i = 0; i < n_threads; ++i) workers.emplace_back(worker);
-  for (auto& w : workers) w.join();
-  return err.load();
+                         float* out, int n_threads, int* chunks_out,
+                         int* threads_out) {
+  return assemble(paths, row_lo, row_hi, out_row0, n_files, c_lo, c_hi, true,
+                  0, reinterpret_cast<unsigned char*>(out), n_threads,
+                  chunks_out, threads_out);
 }
 
 // Raw (no-conversion) multi-file window assembly into a payload-dtype
 // buffer: every file must carry `expect_dtype` or the call fails with
 // EINVAL (the planner guarantees uniformity; this re-checks at the
-// byte level). Same worker-pool structure as tdas_assemble_window.
+// byte level).
 int tdas_assemble_window_raw(const char** paths, const uint64_t* row_lo,
                              const uint64_t* row_hi,
                              const uint64_t* out_row0, int n_files,
                              uint32_t c_lo, uint32_t c_hi,
                              uint32_t expect_dtype, unsigned char* out,
-                             int n_threads) {
-  if (n_files < 0) return EINVAL;
-  if (expect_dtype != 0 && expect_dtype != 1) return EINVAL;
-  const size_t es = dtype_size(expect_dtype);
-  std::atomic<int> next{0};
-  std::atomic<int> err{0};
-  const size_t span_ch = c_hi - c_lo;
-  auto worker = [&]() {
-    for (;;) {
-      int i = next.fetch_add(1);
-      if (i >= n_files || err.load() != 0) return;
-      int fd = open(paths[i], O_RDONLY);
-      if (fd < 0) {
-        err.store(errno ? errno : EIO);
-        return;
-      }
-      TdasHeader h;
-      int rc = read_header_fd(fd, &h);
-      if (rc == 0 && h.dtype != expect_dtype) rc = EINVAL;
-      if (rc == 0 &&
-          (row_hi[i] > h.n_time || c_hi > h.n_ch || row_lo[i] > row_hi[i] ||
-           c_lo > c_hi))
-        rc = ERANGE;
-      if (rc == 0)
-        rc = read_rows_raw(fd, h, row_lo[i], row_hi[i], c_lo, c_hi,
-                           out + out_row0[i] * span_ch * es);
-      close(fd);
-      if (rc != 0) err.store(rc);
-    }
-  };
-  if (n_threads < 1) n_threads = 1;
-  if (n_threads > n_files) n_threads = n_files;
-  std::vector<std::thread> workers;
-  for (int i = 0; i < n_threads; ++i) workers.emplace_back(worker);
-  for (auto& w : workers) w.join();
-  return err.load();
+                             int n_threads, int* chunks_out,
+                             int* threads_out) {
+  return assemble(paths, row_lo, row_hi, out_row0, n_files, c_lo, c_hi,
+                  false, expect_dtype, out, n_threads, chunks_out,
+                  threads_out);
+}
+
+// The row chunks the assemblers cut these segments into, for output
+// rows of out_row_bytes: the first max_chunks as (file, lo, hi,
+// out_row) quadruples into chunks; returns the total count.
+int tdas_plan_window_chunks(const uint64_t* row_lo, const uint64_t* row_hi,
+                            const uint64_t* out_row0, int n_files,
+                            uint64_t out_row_bytes, uint64_t* chunks,
+                            int max_chunks) {
+  const std::vector<Chunk> plan =
+      plan_chunks(row_lo, row_hi, out_row0, n_files, out_row_bytes);
+  for (size_t k = 0; k < plan.size() && k < static_cast<size_t>(max_chunks);
+       ++k) {
+    chunks[4 * k] = static_cast<uint64_t>(plan[k].file);
+    chunks[4 * k + 1] = plan[k].lo;
+    chunks[4 * k + 2] = plan[k].hi;
+    chunks[4 * k + 3] = plan[k].out_row;
+  }
+  return static_cast<int>(plan.size());
 }
 
 }  // extern "C"

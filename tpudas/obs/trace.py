@@ -229,6 +229,14 @@ def span(name: str, **attrs) -> _Span:
     return _Span(name, attrs)
 
 
+def annotate(**attrs) -> None:
+    """Add ``attrs`` to the innermost span open on this thread, for
+    what is known only once its block has run (none open: no-op)."""
+    stack = _span_stack()
+    if stack:
+        stack[-1]["attrs"].update(attrs)
+
+
 def get_spans(name: str | None = None) -> list:
     """Finished spans currently in the ring (oldest first), optionally
     filtered by name.  Returns copies — callers cannot corrupt the

@@ -560,10 +560,12 @@ class LFProc:
 
         tdas-backed directory spools take the native fast path: per-file
         row segments are planned from the index alone and the C++
-        threaded assembler fills ONE contiguous float32 buffer (no
-        per-file Patch objects, no numpy merge copy) on this prefetch
-        thread, handing the block straight to the device kernels
-        (SURVEY.md §3.1 hot loops #2/#3; reference lf_das.py:236-239).
+        threaded assembler fills ONE contiguous buffer (no per-file
+        Patch objects, no numpy merge copy), its reader threads pulling
+        row chunks of every segment, and puts the chunk and thread
+        counts on the ``lfproc.load_window`` span; the block goes
+        straight to the device kernels (SURVEY.md §3.1 hot loops #2/#3;
+        reference lf_das.py:236-239).
         """
         plan_fn = getattr(self._spool, "native_window_plan", None)
         if plan_fn is not None:
